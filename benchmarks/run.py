@@ -5,10 +5,10 @@
 Suites: sampling (Fig 5/6), templates (Table 3), adaptive (Table 4),
 failures (§5.2), moe_shuffle (beyond-paper LM integration).
 
-NOTE: moe_shuffle needs >=8 local devices; when run in the default single-
-device container it reports 'skipped' rows (run with
-XLA_FLAGS=--xla_force_host_platform_device_count=8 to exercise it; the test
-suite does this in-process where safe).
+NOTE: moe_shuffle needs >=8 devices in this process and reports 'skipped'
+rows with fewer; it never starts a child process, because a parent that has
+touched JAX holds the chip.  On the CPU, launch the whole run with
+XLA_FLAGS=--xla_force_host_platform_device_count=8 to exercise it.
 """
 from __future__ import annotations
 

@@ -37,7 +37,7 @@ def main() -> None:
     # per-request stop length (simulates varying generation lengths)
     stops = [int(rng.integers(4, args.max_new)) for _ in range(args.requests)]
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = lm.init_lm(jax.random.key(0), cfg)
 
         @jax.jit

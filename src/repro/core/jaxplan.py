@@ -61,12 +61,12 @@ run would, with the epoch barrier deferred until the whole batch settles —
 per-tenant byte/cost lanes equal serial charges while modelled time pays
 the barrier once.
 
-Precision: the hot path runs in float64 under ``jax.experimental
-.enable_x64`` — byte identity is the acceptance contract, and the
-float32-accumulating Pallas kernels (:mod:`repro.kernels.partition`,
-:mod:`repro.kernels.combine`) remain the PART/COMB primitives of the
-tolerance-validated kernel path (``kernels.ops.part`` / ``kernels.ops
-.combine``, exercised against this executor in ``tests/test_jaxplan.py``).
+Precision: the hot path runs in float64 under ``jax.enable_x64`` — byte
+identity is the acceptance contract, and the float32-accumulating Pallas
+kernels (:mod:`repro.kernels.partition`, :mod:`repro.kernels.combine`)
+remain the PART/COMB primitives of the tolerance-validated kernel path
+(``kernels.ops.part`` / ``kernels.ops.combine``, exercised against this
+executor in ``tests/test_jaxplan.py``).
 
 Decline conditions (the service falls back to the vectorized executor,
 which may fall back to threaded):
@@ -566,43 +566,38 @@ def trace_evictions() -> int:
 
 
 # ---------------------------------------------------------------------------
-# The Pallas kernel plane (default-on on TPU, mirrors vectorized.set_comb_backend)
+# The Pallas kernel plane (explicit opt-in, off on every backend by default)
 # ---------------------------------------------------------------------------
 
-_KERNEL_PLANE: bool | None = None      # None = auto: on when the backend is TPU
+# Off by default: segment_combine's [ndst * distinct keys, block_d] VMEM
+# accumulator runs out of VMEM at the key cardinalities real aggregations
+# have, and partition_permute with num_out = N takes (N / block)^2 grid
+# steps.  The replay's own exact payloads are the result.
+_KERNEL_PLANE = False
 
 
 def kernel_plane_enabled() -> bool:
-    """Whether SUM replays route payloads through the Pallas kernels: an
-    explicit set_kernel_plane() override, else auto — enabled exactly when
-    ``kernels.ops.default_interpret()`` reports a real TPU backend (where
-    the MXU kernels compile natively), off on interpret-mode hosts."""
-    if _KERNEL_PLANE is not None:
-        return _KERNEL_PLANE
-    from repro.kernels import ops as kernel_ops
-    return not kernel_ops.default_interpret()
+    """Whether SUM replays re-fold their payloads through the Pallas kernels
+    (only after an explicit ``set_kernel_plane(True)``)."""
+    return _KERNEL_PLANE
 
 
-def set_kernel_plane(enabled: bool | None) -> bool | None:
-    """Route SUM replays' global PART/COMB through the Pallas MXU kernels:
+def set_kernel_plane(enabled: bool) -> bool:
+    """Opt SUM replays' global PART/COMB into the Pallas MXU kernels:
     :func:`repro.kernels.partition.partition_permute` routes rows to their
     destination-major positions (PART as a one-hot permutation matmul) and
     :func:`repro.kernels.combine.segment_combine` folds per-(destination,
     key) segments (COMB as an accumulating one-hot matmul).
 
-    Default is *auto* (``None``): on when the backend probe reports a TPU,
-    where the kernels compile natively, off in interpret mode on CPU hosts.
-    The kernels accumulate in float32, so on TPU the payload plane trades
-    the bit-exact float64 contract for MXU throughput — ``set_kernel_plane
-    (False)`` is the opt-out that restores exact payloads (routing
-    decisions, output key sets, and all ledger charges always come from the
-    exact program either way; skew-scattered replays keep exact payloads
-    unconditionally).  Returns the previous setting (``True``/``False``/
-    ``None``) so callers can restore it.
+    The kernels accumulate in float32, so output payloads then match the
+    exact plane only to float32 tolerance; routing decisions, output key
+    sets and all ledger charges still come from the exact program, and
+    skew-scattered replays keep exact payloads unconditionally.  Returns the
+    previous setting so callers can restore it.
     """
     global _KERNEL_PLANE
     prev = _KERNEL_PLANE
-    _KERNEL_PLANE = None if enabled is None else bool(enabled)
+    _KERNEL_PLANE = bool(enabled)
     return prev
 
 
@@ -863,7 +858,7 @@ def prepare_batch(cluster: LocalCluster, members) -> "_BatchHandle | None":
     its own tenant's ledger lanes exactly as a serial run would."""
     if len(members) < 2:
         return None
-    from jax.experimental import enable_x64
+    import jax
 
     args0, bufs0 = members[0]
     low = get_lowering(args0.plan)
@@ -883,7 +878,7 @@ def prepare_batch(cluster: LocalCluster, members) -> "_BatchHandle | None":
     kind, shared = _program_inputs(spec, low)
     sig = (spec, keys.shape[1:], vals.shape[1:],
            tuple(a.shape for a in shared))
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _program(kind, sig, batch=len(members))(
             spec, keys, vals, owner, *shared)
     arrs = [np.asarray(a) for a in out]
@@ -985,7 +980,7 @@ def _charge_two_level(ledger, topo, args, low, gmoved_init, post1, p3moved,
 def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
                  low: JaxLowering, manager,
                  batch_slot: "_BatchSlot | None" = None) -> ShuffleResult:
-    from jax.experimental import enable_x64
+    import jax
 
     plan = args.plan
     topo = cluster.topology
@@ -1020,7 +1015,7 @@ def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
             "jit_replay", shuffle_id=args.shuffle_id, tenant=args.tenant,
             rows=int(keys.shape[0]), traces_before=replay_cache_size(),
         ) if tracer.enabled else None
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _program(kind, sig)(spec, keys, vals, owner, *shared)
         if jit_sp is not None:
             jit_sp.end(traces_after=replay_cache_size())
@@ -1107,9 +1102,9 @@ def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
                            f_vals[mask].reshape(-1, width))
     if (kernel_plane_enabled() and spec.comb == "sum" and not spec.skew
             and spec.template not in ("bruck", "two_level")):
-        # Pallas plane (default-on on TPU): same routing and key sets,
-        # payloads re-folded on the MXU kernels (float32 accumulation —
-        # see set_kernel_plane)
+        # Pallas plane (opt-in): same routing and key sets, payloads
+        # re-folded on the MXU kernels (float32 accumulation — see
+        # set_kernel_plane)
         for d, (kk, vv) in zip(dsts,
                                kernel_global_stage(args.part_fn, keys, vals,
                                                    len(dsts))):

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
 
 # ---------------------------------------------------------------------------
 # SEND/RECV: neighbor exchange on a ring (the coordinated-template analogue)
@@ -33,7 +32,7 @@ from repro.compat import axis_size
 
 def ring_exchange(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
     """SEND to (i+shift), RECV from (i-shift) along a mesh axis."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -59,7 +58,7 @@ def two_level_all_to_all(x: jax.Array, outer_axis: str, inner_axis: str) -> jax.
     per chip instead of ``O(outer·inner)``, with the cross-DCN stage carrying
     contiguous per-pod aggregates (the Lambada/TeShu two-level template on a mesh).
     """
-    o, i = axis_size(outer_axis), axis_size(inner_axis)
+    o, i = lax.axis_size(outer_axis), lax.axis_size(inner_axis)
     assert x.shape[0] == o and x.shape[1] == i, (x.shape, o, i)
     # stage 1 (fast axis): deliver the destination-inner dimension within each pod
     y = lax.all_to_all(x, inner_axis, split_axis=1, concat_axis=1, tiled=True)
@@ -103,7 +102,7 @@ def hier_psum(
     with int8 compression) versus a flat all-reduce — the mesh instantiation of the
     paper's S->R->G schedule.
     """
-    n_inner = axis_size(inner_axis)
+    n_inner = lax.axis_size(inner_axis)
     orig_shape = x.shape
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % n_inner

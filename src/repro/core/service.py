@@ -53,9 +53,11 @@ Execution modes (cluster default, overridable per tenant and per call):
   cache (plans are still compiled and stored, so switching back to ``auto`` hits).
 
 The ``executor`` knob picks which data plane an ``"auto"`` cache hit replays
-on — ``"vectorized"`` (batched numpy, the default) or ``"jax"`` (one jitted
-``lax.scan`` program per plan, :mod:`repro.core.jaxplan`); plans the jax
-lowering declines fall back to vectorized, then threaded, byte-identically.
+on — ``"vectorized"`` (batched numpy on the host) or ``"jax"`` (one jitted
+``lax.scan`` program per plan, :mod:`repro.core.jaxplan`).  Unset, it follows
+the platform (:func:`default_executor`): ``"jax"`` where JAX's default
+backend is a TPU, ``"vectorized"`` elsewhere.  Plans the jax lowering
+declines fall back to vectorized, then threaded, byte-identically.
 
 Streaming modes pick the execution model (:mod:`repro.core.streaming`):
 
@@ -129,6 +131,14 @@ STREAMING_MODES = ("off", "auto")
 # lowering declines (triggered skew, streaming, fault state, exotic
 # part/comb fns).  The fresh/instantiation path is always threaded.
 EXECUTORS = ("vectorized", "jax")
+
+
+def default_executor() -> str:
+    """The replay plane a cluster uses when none is given: the jitted device
+    plane where JAX's default backend is a TPU, batched numpy elsewhere."""
+    import jax
+    return "jax" if jax.default_backend() == "tpu" else "vectorized"
+
 
 # The per-call / per-tenant / cluster-default knob stack.  Every knob here may
 # be set on the cluster (the fleet default), overridden at tenant registration
@@ -324,7 +334,7 @@ class TeShuCluster:
                  journal_path: str | None = None,
                  replicas: Sequence[str] = (),
                  plan_cache: PlanCache | None = None,
-                 execution: str = "auto", executor: str = "vectorized",
+                 execution: str = "auto", executor: str | None = None,
                  resilience: str = "off",
                  balance: str = "off",
                  skew_threshold: float = DEFAULT_SKEW_THRESHOLD,
@@ -346,6 +356,8 @@ class TeShuCluster:
                  elastic_hysteresis: int = 2,
                  elastic_ttl_s: float | None = None):
         _check_mode("execution", execution, EXECUTION_MODES)
+        if executor is None:
+            executor = default_executor()
         _check_mode("executor", executor, EXECUTORS)
         _check_mode("resilience", resilience, RESILIENCE_MODES)
         _check_mode("balance", balance, BALANCE_MODES)
@@ -1355,7 +1367,7 @@ class TeShuService(TeShuCluster):
                  journal_path: str | None = None,
                  replicas: Sequence[str] = (),
                  plan_cache: PlanCache | None = None,
-                 execution: str = "auto", executor: str = "vectorized",
+                 execution: str = "auto", executor: str | None = None,
                  resilience: str = "off",
                  balance: str = "off",
                  skew_threshold: float = DEFAULT_SKEW_THRESHOLD,

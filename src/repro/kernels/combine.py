@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_D = 512
@@ -83,7 +82,7 @@ def _segment_combine(
         out_specs=pl.BlockSpec((num_segments, block_d), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((num_segments, d_p), vals.dtype),
         scratch_shapes=[pltpu.VMEM((num_segments, block_d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ids2, vals)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 DEFAULT_BLOCK_KV = 512
 _NEG = -1e30
@@ -106,7 +105,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * kvh, g, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(valid_len, jnp.int32).reshape(1), qg, k, v)

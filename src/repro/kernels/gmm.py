@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_D = 512
@@ -80,7 +79,7 @@ def gmm(
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, f), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tile_group_ids.astype(jnp.int32), x, w)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 DEFAULT_BLOCK_IN = 256
 DEFAULT_BLOCK_OUT = 256
@@ -92,7 +91,7 @@ def _partition_permute(
         out_specs=pl.BlockSpec((block_out, block_d), lambda j, o, i: (o, j)),
         out_shape=jax.ShapeDtypeStruct((o_p, d_p), vals.dtype),
         scratch_shapes=[pltpu.VMEM((block_out, block_d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ids[:, None], vals)

@@ -7,7 +7,7 @@ first init, and the production meshes need 512 host placeholder devices.
 
 Per cell this runs::
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=..., out_shardings=...).lower(**specs)
         compiled = lowered.compile()
         print(compiled.memory_analysis())   # proves it fits
@@ -31,7 +31,7 @@ import traceback
 import jax
 
 from repro.configs import ARCHS, SHAPES, shape_applicable
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.roofline import analyze
 from repro.launch.steps import build_cell
 
@@ -39,7 +39,7 @@ from repro.launch.steps import build_cell
 def run_cell(arch: str, shape_name: str, mesh, *, verbose: bool = True) -> dict:
     t0 = time.time()
     cell = build_cell(arch, shape_name, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = cell.lower()
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -61,7 +61,8 @@ def run_cell(arch: str, shape_name: str, mesh, *, verbose: bool = True) -> dict:
         except Exception as e:                            # pragma: no cover
             print(f"    memory_analysis unavailable: {e}")
         roof = analyze(compiled, arch=arch, shape=SHAPES[shape_name], mesh=mesh,
-                       cfg=cell.cfg)
+                       cfg=cell.cfg,
+                       device_kind=PRODUCTION_DEVICE_KIND)
         row = roof.row()
         row.update({"status": "ok", "lower_s": round(t_lower, 1),
                     "compile_s": round(t_compile, 1), "memory": mem})

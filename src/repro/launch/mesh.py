@@ -11,15 +11,15 @@ from __future__ import annotations
 
 import jax
 
+# jax's device_kind of the production chip (v5e): the peaks the dry-run's
+# roofline is taken against.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
 
 def _mesh(dev_array, axes) -> jax.sharding.Mesh:
-    """Build a Mesh across jax versions: ``AxisType`` (explicit-sharding API)
-    does not exist on older releases, where Auto is the only behavior anyway."""
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        return jax.sharding.Mesh(dev_array, axes,
-                                 axis_types=(axis_type,) * len(axes))
-    return jax.sharding.Mesh(dev_array, axes)
+    return jax.sharding.Mesh(
+        dev_array, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -16,7 +16,7 @@ import jax
 
 from repro.configs import ARCHS, SHAPES
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.roofline import analyze
 from repro.launch.steps import Recipe, build_cell, recipe_for
 
@@ -57,10 +57,11 @@ def run(arch: str, shape: str, *, multi_pod: bool, recipe: Recipe,
     set_block_defaults(block_q, block_kv)
     mesh = make_production_mesh(multi_pod=multi_pod)
     cell = build_cell(arch, shape, mesh, recipe=recipe)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = cell.lower().compile()
     roof = analyze(compiled, arch=arch, shape=SHAPES[shape], mesh=mesh,
-                   cfg=cell.cfg)
+                   cfg=cell.cfg,
+                   device_kind=PRODUCTION_DEVICE_KIND)
     row = roof.row()
     print(f"\n=== {label or 'cell'}: {arch} x {shape} on {row['mesh']} ===")
     print(f"  compute    {roof.compute_s*1e3:12.1f} ms")

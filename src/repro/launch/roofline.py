@@ -2,9 +2,12 @@
 
 Three terms per (arch x shape x mesh) cell, all in seconds:
 
-    compute_s    = HLO_FLOPs_per_chip / peak_FLOP/s          (197 TF bf16, v5e)
-    memory_s     = HLO_bytes_per_chip / HBM_bw               (819 GB/s)
+    compute_s    = HLO_FLOPs_per_chip / peak_FLOP/s
+    memory_s     = HLO_bytes_per_chip / HBM_bw
     collective_s = ici_wire_bytes/chip / ici_bw  +  dcn_wire_bytes/chip / dcn_bw
+
+with the peaks of the target chip looked up by its jax ``device_kind``
+(:data:`PEAKS`); a kind with no published peaks is an error.
 
 ``cost_analysis()`` on the compiled (post-SPMD) module is already per-chip.
 Collective bytes are NOT in cost_analysis: we parse the optimized HLO, resolve
@@ -28,12 +31,41 @@ import re
 
 import numpy as np
 
-# v5e per-chip constants (assignment-specified)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-DCN_BW = 6.25e9
+from repro.core import topology
+
 POD_SIZE = 256
+
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Per-chip peaks, in FLOP/s and bytes/s."""
+
+    flops: float
+    hbm_bw: float
+    ici_bw: float           # per ICI link
+    dcn_bw: float           # per chip across pods
+
+
+# Keyed by jax's ``device_kind``.  "TPU v5 lite" (v5e): Google Cloud TPU
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# ICI over 4 links (50 GB/s each).  The DCN share is the modelled 50 Gbit/s
+# NIC share per chip of repro.core.topology, not a published figure.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=topology.TPU_PEAK_FLOPS_BF16,
+                         hbm_bw=topology.TPU_HBM_BW,
+                         ici_bw=topology.TPU_ICI_BW_PER_LINK,
+                         dcn_bw=topology.TPU_DCN_BW_PER_CHIP),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -174,6 +206,7 @@ class Roofline:
     ici_bytes_per_chip: float
     dcn_bytes_per_chip: float
     model_flops: float             # 6*N*D (train) / 2*N*D (serve), global
+    peaks: Peaks                   # of the target chip
     collective_count: int = 0
     per_chip_hbm_gb: float = 0.0   # argument+temp from memory_analysis
     flash_bytes_per_chip: float = 0.0  # XLA-path attention traffic the Pallas
@@ -181,22 +214,23 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes_per_chip / HBM_BW
+        return self.hbm_bytes_per_chip / self.peaks.hbm_bw
 
     @property
     def memory_s_kernel(self) -> float:
         """Memory term with the Pallas flash kernel: the tagged attention
         inner-loop traffic (logits / online-softmax state) lives in VMEM."""
         return max(0.0, self.hbm_bytes_per_chip
-                   - self.flash_bytes_per_chip) / HBM_BW
+                   - self.flash_bytes_per_chip) / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.ici_bytes_per_chip / ICI_BW + self.dcn_bytes_per_chip / DCN_BW
+        return (self.ici_bytes_per_chip / self.peaks.ici_bw
+                + self.dcn_bytes_per_chip / self.peaks.dcn_bw)
 
     @property
     def dominant(self) -> str:
@@ -221,7 +255,7 @@ class Roofline:
         t = self.step_time_s
         if t <= 0:
             return 0.0
-        return self.model_flops / (t * self.chips * PEAK_FLOPS)
+        return self.model_flops / (t * self.chips * self.peaks.flops)
 
     def row(self) -> dict:
         return {
@@ -254,8 +288,10 @@ def model_flops_for(cfg, shape) -> float:
     return 2.0 * n * tokens
 
 
-def analyze(compiled, *, arch: str, shape, mesh, cfg) -> Roofline:
-    """Loop-aware roofline from the compiled HLO (see hlo_analysis).
+def analyze(compiled, *, arch: str, shape, mesh, cfg,
+            device_kind: str) -> Roofline:
+    """Loop-aware roofline from the compiled HLO (see hlo_analysis), against
+    the peaks of ``device_kind``, the chip the mesh stands for.
 
     ``cost_analysis()`` counts while bodies once; scans (layers, microbatches,
     attention blocks) would be under-counted by orders of magnitude, so flops /
@@ -280,5 +316,6 @@ def analyze(compiled, *, arch: str, shape, mesh, cfg) -> Roofline:
         chips=chips, flops_per_chip=cost.flops, hbm_bytes_per_chip=cost.hbm_bytes,
         ici_bytes_per_chip=cost.ici_bytes, dcn_bytes_per_chip=cost.dcn_bytes,
         model_flops=model_flops_for(cfg, shape),
+        peaks=peaks_for(device_kind),
         collective_count=int(cost.collective_count), per_chip_hbm_gb=hbm_gb,
         flash_bytes_per_chip=cost.flash_bytes)

@@ -44,7 +44,7 @@ def serve(arch: str, *, smoke: bool = True, batch: int = 4, prompt_len: int = 32
                                 model_parallel=min(2, len(jax.devices())))
     ep = ep_axes_for(mesh) if cfg.family == "moe" else ()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if params is None:
             params = lm.init_lm(jax.random.key(seed), cfg)
         p_sh = to_named(param_specs(params, mesh, cfg), mesh)

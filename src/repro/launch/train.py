@@ -65,7 +65,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 20,
         journal_path=f"{ckpt_dir}/shuffle_journal.jsonl" if ckpt_dir else None,
         plan_cache=PlanCache(capacity=64))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = lm.init_lm(jax.random.key(seed), cfg)
         opt_state = init_opt_state(params, recipe.moment_dtype)
         p_specs = param_specs(params, mesh, cfg)

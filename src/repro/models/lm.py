@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import shard_map
-
 from .config import ModelConfig
 from .hybrid import hymba_mixer, init_hymba_block
 from .layers import (Params, _dtype, attention, embed_init, init_attention,
@@ -141,7 +139,7 @@ def _block_apply(p: Params, cfg: ModelConfig, layer: int, x, positions,
     x = x + out
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
-        y, aux = moe_ffn(p["moe"], cfg, h2, mesh_axes=ep_axes)
+        y, aux, _ = moe_ffn(p["moe"], cfg, h2, mesh_axes=ep_axes)
         x = x + y
     else:
         x = x + mlp(p["mlp"], h2)
@@ -178,7 +176,7 @@ def _embed_lookup(table: jax.Array, tokens: jax.Array) -> jax.Array:
         x = tbl[tok]                           # local gather
         return lax.all_gather(x, "model", axis=2, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, "model"), P(b_axes, None)),
         out_specs=P(b_axes, None, None),

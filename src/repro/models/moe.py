@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import meshops
 
 from .config import ModelConfig
@@ -108,8 +107,11 @@ def _combine(out_buf, wbuf, meta, t, d):
 
 
 def moe_ffn(p: Params, cfg: ModelConfig, x: jax.Array, *,
-            mesh_axes: tuple[str, ...] = ()) -> tuple[jax.Array, jax.Array]:
-    """x: [B, S, D] -> ([B, S, D], aux loss).  ``mesh_axes`` = EP mesh axes."""
+            mesh_axes: tuple[str, ...] = ()
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """x: [B, S, D] -> ([B, S, D], aux loss, dropped).  ``mesh_axes`` = EP
+    mesh axes; ``dropped`` counts the (token, expert) assignments that found
+    their expert's buffer full, over the whole batch."""
     m = cfg.moe
     b, s, d = x.shape
     out = jnp.zeros_like(x)
@@ -121,11 +123,11 @@ def moe_ffn(p: Params, cfg: ModelConfig, x: jax.Array, *,
 
     dispatch = m.dispatch if mesh_axes else "gspmd"
     if dispatch == "gspmd" or not mesh_axes:
-        y, aux = _moe_gspmd(p, cfg, x, mesh_axes)
+        y, aux, dropped = _moe_gspmd(p, cfg, x, mesh_axes)
     else:
-        y, aux = _moe_shard_map(p, cfg, x, mesh_axes,
-                                two_level=(dispatch == "teshu2"))
-    return out + y, aux
+        y, aux, dropped = _moe_shard_map(p, cfg, x, mesh_axes,
+                                         two_level=(dispatch == "teshu2"))
+    return out + y, aux, dropped
 
 
 # ---------------------------------------------------------------------------
@@ -133,7 +135,8 @@ def moe_ffn(p: Params, cfg: ModelConfig, x: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 def _moe_gspmd(p: Params, cfg: ModelConfig, x: jax.Array,
-               mesh_axes: tuple[str, ...]) -> tuple[jax.Array, jax.Array]:
+               mesh_axes: tuple[str, ...]
+               ) -> tuple[jax.Array, jax.Array, jax.Array]:
     m = cfg.moe
     b, s, d = x.shape
     x_flat = x.reshape(b * s, d)
@@ -146,7 +149,8 @@ def _moe_gspmd(p: Params, cfg: ModelConfig, x: jax.Array,
     y = _expert_ffn(p["experts"], buf)
     if mesh_axes:
         y = lax.with_sharding_constraint(y, P(mesh_axes, None, None))
-    return _combine(y, wbuf, meta, b * s, d).reshape(b, s, d), aux
+    dropped = jnp.sum(~meta[1], dtype=jnp.int32)
+    return _combine(y, wbuf, meta, b * s, d).reshape(b, s, d), aux, dropped
 
 
 # ---------------------------------------------------------------------------
@@ -160,7 +164,7 @@ def _capacity(tokens: int, m) -> int:
 
 def _moe_shard_map(p: Params, cfg: ModelConfig, x: jax.Array,
                    ep_axes: tuple[str, ...], *, two_level: bool
-                   ) -> tuple[jax.Array, jax.Array]:
+                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Explicit expert-parallel dispatch through the shuffle layer.
 
     Geometry: tokens stay sharded over the batch axes ``('pod','data')``; experts
@@ -217,13 +221,19 @@ def _moe_shard_map(p: Params, cfg: ModelConfig, x: jax.Array,
             y = lax.all_gather(y, "model", axis=0, tiled=True)
         aux = lax.pmean(aux, tuple(a for a in ("pod", "data", "model")
                                    if a in mesh.shape))
-        return y.reshape(bl, s, d), aux
+        # every batch shard routes its own tokens, and so does every model
+        # coordinate when the routing work is sliced over 'model'
+        dropped = jnp.sum(~meta[1], dtype=jnp.int32)
+        owners = batch_axes + (("model",) if do_slice else ())
+        if owners:
+            dropped = lax.psum(dropped, owners)
+        return y.reshape(bl, s, d), aux, dropped
 
     batch_spec = P(batch_axes if batch_axes else None, None, None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(batch_spec, P(), P(ep_axes, None, None)),
-        out_specs=(batch_spec, P()),
+        out_specs=(batch_spec, P(), P()),
         check_vma=False,
     )(x, p["router"], p["experts"])
 
@@ -240,17 +250,8 @@ def _ep_shuffle(x: jax.Array, ep_axes: tuple[str, ...], mesh, two_level: bool):
 
 
 def _current_mesh():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:      # newer jax: jax.set_mesh style
-        mesh = get_abstract()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    try:        # `with mesh:` context (physical mesh), pre-set_mesh style
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    raise RuntimeError("moe shard_map dispatch requires an active mesh "
-                       "(run under `with mesh:` / jax.set_mesh)")
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        raise RuntimeError("moe shard_map dispatch requires an active mesh "
+                           "(run under `with jax.set_mesh(mesh):`)")
+    return mesh

@@ -4,6 +4,7 @@ jax fixes the device count at first init, so these run in subprocesses with
 XLA_FLAGS set (same pattern as launch/dryrun.py).  Each subprocess prints
 CHECK lines that the parent asserts on.
 """
+import json
 import os
 import subprocess
 import sys
@@ -45,14 +46,37 @@ def test_moe_dispatch_templates_match_local():
                                             capacity_factor=8.0))
             p = init_moe(jax.random.key(7), cfg)
             x = jax.random.normal(jax.random.key(8), (4, 16, 32))
-            with mesh:
-                y_ref, _ = moe_ffn(p, cfg, x, mesh_axes=())
-                y, _ = jax.jit(lambda p, x: moe_ffn(
+            with jax.set_mesh(mesh):
+                y_ref, _, drop_ref = moe_ffn(p, cfg, x, mesh_axes=())
+                y, _, drop = jax.jit(lambda p, x: moe_ffn(
                     p, cfg, x, mesh_axes=("pod", "model")))(p, x)
             err = float(jnp.max(jnp.abs(y - y_ref)))
-            print(f"CHECK {disp} err={err:.2e} ok={err < 1e-5}")
+            same_drops = int(drop) == int(drop_ref) == 0
+            print(f"CHECK {disp} err={err:.2e} ok={err < 1e-5 and same_drops}")
     """)
     assert out.count("ok=True") == 2, out
+
+
+def test_chip_smoke_four_chip_phase_small():
+    """chip_smoke.py --four-chip's phase at reduced width on 4 CPU devices:
+    experts spread over the mesh, outputs and dropped counts match one
+    device."""
+    out = run_sub(f"""
+        import sys
+        sys.path.insert(0, {REPO!r})
+        import chip_smoke
+        from repro.models.config import ModelConfig, MoEConfig
+        cfg = ModelConfig(name="m", family="moe", d_model=128, n_heads=2,
+                          n_kv_heads=2, d_head=64, d_ff=64, vocab=64,
+                          moe=MoEConfig(num_experts=16, top_k=4,
+                                        d_ff_expert=64, capacity_factor=1.25,
+                                        dispatch="teshu2"))
+        print("CHECK", chip_smoke.four_chip_phase(0, cfg=cfg, batch=4, seq=64))
+    """, devices=4)
+    assert "CHECK []" in out, out
+    moe = [json.loads(line) for line in out.splitlines()
+           if line.startswith("{")]
+    assert [r["dropped"] for r in moe if "dropped" in r][0] > 0, out
 
 
 def test_hier_psum_equals_flat():
@@ -69,9 +93,8 @@ def test_hier_psum_equals_flat():
                 return meshops.grad_sync({"g": v}, inner_axis="data",
                                          outer_axis="pod", mode=mode,
                                          compress_outer=compress)["g"]
-            from repro.compat import P, shard_map
-            return jax.jit(shard_map(
-                f, mesh=mesh, in_specs=P(), out_specs=P(),
+            return jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=jax.P(), out_specs=jax.P(),
                 check_vma=False))(x)
 
         flat = run("flat", False)
@@ -92,7 +115,7 @@ def test_embed_lookup_sharded_matches_plain():
         mesh = make_mesh((2, 4), ("data", "model"))
         table = jax.random.normal(jax.random.key(1), (64, 32))
         toks = jax.random.randint(jax.random.key(2), (4, 6), 0, 64)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(_embed_lookup)(table, toks)
         err = float(jnp.max(jnp.abs(got - table[toks])))
         print("CHECK", err < 1e-6)
@@ -112,7 +135,7 @@ def test_train_step_under_mesh_runs_and_learns():
 
         cfg = get_config("deepseek-v2-236b", smoke=True)
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
-        with mesh:
+        with jax.set_mesh(mesh):
             params = lm.init_lm(jax.random.key(0), cfg)
             opt = init_opt_state(params)
             step = make_train_step(cfg, AdamWConfig(lr=1e-2, warmup_steps=1,

@@ -131,9 +131,10 @@ def test_moe_gspmd_math():
                                     capacity_factor=8.0))
     p = init_moe(jax.random.key(9), cfg)
     x = jax.random.normal(jax.random.key(10), (1, 6, 16))
-    y, aux = moe_ffn(p, cfg, x)
+    y, aux, dropped = moe_ffn(p, cfg, x)
     assert y.shape == x.shape
     assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(aux))
+    assert int(dropped) == 0
     # top_k == num_experts with huge capacity: output == full softmax mixture
     logits = (x.reshape(-1, 16) @ p["router"]).astype(jnp.float32)
     w = jax.nn.softmax(logits, -1)

@@ -174,6 +174,13 @@ def test_checkpoint_shape_mismatch_raises(tmp_path):
 # HLO analyzer unit tests (the roofline's measurement tool)
 # ---------------------------------------------------------------------------
 
+def test_roofline_peaks_are_keyed_by_device_kind():
+    from repro.launch.roofline import peaks_for
+    assert peaks_for("TPU v5 lite").flops == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
+
+
 def test_hlo_analyzer_scales_loops():
     from repro.launch.hlo_analysis import analyze_hlo
     from jax import lax
