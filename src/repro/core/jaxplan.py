@@ -302,25 +302,27 @@ def _skew_slot(keys, owner, alive, base_slot, ns, hot_keys, share_slots,
     owner IS that worker's buffer order, the byte-order invariant the sorts
     maintain — computed with one stable (owner, key) lexsort and a
     segment-relative position."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
-    n = keys.shape[0]
-    pos = jnp.arange(n)
-    so = jnp.where(alive, jnp.minimum(owner, ns - 1), ns)
-    perm = jnp.argsort(jnp.where(alive, keys, jnp.int64(0)), stable=True)
-    perm = perm[jnp.argsort(so[perm], stable=True)]
-    sk, sso = keys[perm], so[perm]
-    prev_same = ((sso == jnp.roll(sso, 1))
-                 & (sk == jnp.roll(sk, 1))).at[0].set(False)
-    seg_start = lax.cummax(jnp.where(~prev_same, pos, 0))
-    occ = jnp.zeros((n,), jnp.int64).at[perm].set(pos - seg_start)
-    hp = jnp.searchsorted(hot_keys, keys)
-    hpc = jnp.minimum(hp, hot_keys.shape[0] - 1)
-    is_hot = (hot_keys[hpc] == keys) & alive
-    share = share_slots[hpc,
-                        (occ % jnp.maximum(share_len[hpc], 1)).astype(jnp.int32)]
-    return jnp.where(is_hot, share.astype(jnp.int32), base_slot)
+    with jax.named_scope("teshu/skew_slot"):
+        n = keys.shape[0]
+        pos = jnp.arange(n)
+        so = jnp.where(alive, jnp.minimum(owner, ns - 1), ns)
+        perm = jnp.argsort(jnp.where(alive, keys, jnp.int64(0)), stable=True)
+        perm = perm[jnp.argsort(so[perm], stable=True)]
+        sk, sso = keys[perm], so[perm]
+        prev_same = ((sso == jnp.roll(sso, 1))
+                     & (sk == jnp.roll(sk, 1))).at[0].set(False)
+        seg_start = lax.cummax(jnp.where(~prev_same, pos, 0))
+        occ = jnp.zeros((n,), jnp.int64).at[perm].set(pos - seg_start)
+        hp = jnp.searchsorted(hot_keys, keys)
+        hpc = jnp.minimum(hp, hot_keys.shape[0] - 1)
+        is_hot = (hot_keys[hpc] == keys) & alive
+        share = share_slots[
+            hpc, (occ % jnp.maximum(share_len[hpc], 1)).astype(jnp.int32)]
+        return jnp.where(is_hot, share.astype(jnp.int32), base_slot)
 
 
 def _combine(comb: str, keys, vals, owner, alive, participate, sentinel: int):
@@ -334,19 +336,21 @@ def _combine(comb: str, keys, vals, owner, alive, participate, sentinel: int):
     rows die (owner keeps its value; every later sort sends dead rows to
     the end via the alive mask).
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
-    folds = participate & alive
-    ckey = jnp.where(folds, keys, jnp.int64(0))
-    perm = jnp.argsort(ckey, stable=True)
-    so = jnp.where(alive, owner, sentinel)
-    perm = perm[jnp.argsort(so[perm], stable=True)]
-    keys, vals, owner, alive, folds = (
-        keys[perm], vals[perm], owner[perm], alive[perm], folds[perm])
-    prev_same = ((owner == jnp.roll(owner, 1))
-                 & (keys == jnp.roll(keys, 1))).at[0].set(False)
-    is_start = ~(prev_same & folds)
+    with jax.named_scope("teshu/comb_sort"):
+        folds = participate & alive
+        ckey = jnp.where(folds, keys, jnp.int64(0))
+        perm = jnp.argsort(ckey, stable=True)
+        so = jnp.where(alive, owner, sentinel)
+        perm = perm[jnp.argsort(so[perm], stable=True)]
+        keys, vals, owner, alive, folds = (
+            keys[perm], vals[perm], owner[perm], alive[perm], folds[perm])
+        prev_same = ((owner == jnp.roll(owner, 1))
+                     & (keys == jnp.roll(keys, 1))).at[0].set(False)
+        is_start = ~(prev_same & folds)
     op = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[comb]
 
     def fold(acc, x):
@@ -354,8 +358,9 @@ def _combine(comb: str, keys, vals, owner, alive, participate, sentinel: int):
         acc = jnp.where(start, v, op(acc, v))
         return acc, acc
 
-    _, folded = lax.scan(fold, jnp.zeros_like(vals[0]), (vals, is_start))
-    seg_end = jnp.concatenate([is_start[1:], jnp.ones((1,), bool)])
+    with jax.named_scope("teshu/comb_fold"):
+        _, folded = lax.scan(fold, jnp.zeros_like(vals[0]), (vals, is_start))
+        seg_end = jnp.concatenate([is_start[1:], jnp.ones((1,), bool)])
     return keys, folded, owner, alive & seg_end
 
 
@@ -364,6 +369,7 @@ def _replay_impl(spec: _PlanSpec, keys, vals, owner,
                  hot_keys, share_slots, share_len):
     """The rolled-scan replay shared by the four regular templates and (with
     zero levels plus a simulated global_rank) bruck."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -389,11 +395,12 @@ def _replay_impl(spec: _PlanSpec, keys, vals, owner,
         # the exchange: one stable sort by (receiver, fold rank); within
         # a (sender -> receiver) flow rows keep buffer order = the stable
         # argsort inside messages.partition
-        sort_owner = jnp.where(alive, new_owner, ns)
-        ck = sort_owner.astype(jnp.int64) * jnp.int64(ns + 1) + rank
-        perm = jnp.argsort(ck, stable=True)
-        keys2, vals2 = keys[perm], vals[perm]
-        owner2, alive2 = new_owner[perm], alive[perm]
+        with jax.named_scope("teshu/exchange"):
+            sort_owner = jnp.where(alive, new_owner, ns)
+            ck = sort_owner.astype(jnp.int64) * jnp.int64(ns + 1) + rank
+            perm = jnp.argsort(ck, stable=True)
+            keys2, vals2 = keys[perm], vals[perm]
+            owner2, alive2 = new_owner[perm], alive[perm]
         staged_owner = act & (g_l[jnp.minimum(owner2, ns - 1)] > 1)
         if spec.comb is not None:
             keys2, vals2, owner2, alive2 = _combine(
@@ -421,10 +428,11 @@ def _replay_impl(spec: _PlanSpec, keys, vals, owner,
     gmoved = jnp.zeros((ns, ndst), jnp.int32).at[oc, sc].add(
         alive.astype(jnp.int32))
     rank = jnp.where(alive, global_rank[oc, sc], 0)
-    ck = new_owner.astype(jnp.int64) * jnp.int64(ns + 1) + rank
-    perm = jnp.argsort(ck, stable=True)
-    keys, vals = keys[perm], vals[perm]
-    owner, alive = new_owner[perm], alive[perm]
+    with jax.named_scope("teshu/exchange"):
+        ck = new_owner.astype(jnp.int64) * jnp.int64(ns + 1) + rank
+        perm = jnp.argsort(ck, stable=True)
+        keys, vals = keys[perm], vals[perm]
+        owner, alive = new_owner[perm], alive[perm]
     if spec.comb is not None:
         keys, vals, owner, alive = _combine(
             spec.comb, keys, vals, owner, alive, alive, ndst)
@@ -443,6 +451,7 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
     stable sort on the grid's exact mailbox concat order: (receiver, sender
     member index, slot).  Returns the phase flow counts the ledger replays.
     """
+    import jax
     import jax.numpy as jnp
 
     ns = spec.ns
@@ -456,9 +465,11 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
     w1 = (owner // q) * q + d // q
     rank1 = (owner % q).astype(jnp.int64) * ns + d
     gmoved_init = jnp.zeros((ns, ns), jnp.int32).at[owner, d].add(1)
-    ck = w1.astype(jnp.int64) * jnp.int64(q * ns) + rank1
-    perm = jnp.argsort(ck, stable=True)
-    keys, vals, owner, alive = keys[perm], vals[perm], w1[perm], alive[perm]
+    with jax.named_scope("teshu/exchange"):
+        ck = w1.astype(jnp.int64) * jnp.int64(q * ns) + rank1
+        perm = jnp.argsort(ck, stable=True)
+        keys, vals, owner, alive = (keys[perm], vals[perm], w1[perm],
+                                    alive[perm])
     if spec.comb is not None:
         keys, vals, owner, alive = _combine(
             spec.comb, keys, vals, owner, alive, alive, ns)
@@ -473,11 +484,12 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
     rank3 = owner % q
     p3moved = jnp.zeros((ns, ns), jnp.int32).at[
         jnp.minimum(owner, ns - 1), d].add(alive.astype(jnp.int32))
-    so = jnp.where(alive, d, ns)
-    ck = so.astype(jnp.int64) * jnp.int64(q) + rank3
-    perm = jnp.argsort(ck, stable=True)
-    keys, vals, alive = keys[perm], vals[perm], alive[perm]
-    owner = d[perm]
+    with jax.named_scope("teshu/exchange"):
+        so = jnp.where(alive, d, ns)
+        ck = so.astype(jnp.int64) * jnp.int64(q) + rank3
+        perm = jnp.argsort(ck, stable=True)
+        keys, vals, alive = keys[perm], vals[perm], alive[perm]
+        owner = d[perm]
     if spec.comb is not None:
         keys, vals, owner, alive = _combine(
             spec.comb, keys, vals, owner, alive, alive, ns)
@@ -858,8 +870,6 @@ def prepare_batch(cluster: LocalCluster, members) -> "_BatchHandle | None":
     its own tenant's ledger lanes exactly as a serial run would."""
     if len(members) < 2:
         return None
-    import jax
-
     args0, bufs0 = members[0]
     low = get_lowering(args0.plan)
     if low is None or low is _DECLINED:
@@ -878,10 +888,9 @@ def prepare_batch(cluster: LocalCluster, members) -> "_BatchHandle | None":
     kind, shared = _program_inputs(spec, low)
     sig = (spec, keys.shape[1:], vals.shape[1:],
            tuple(a.shape for a in shared))
-    with jax.enable_x64(True):
-        out = _program(kind, sig, batch=len(members))(
-            spec, keys, vals, owner, *shared)
-    arrs = [np.asarray(a) for a in out]
+    arrs = _dispatch(cluster.obs.tracer, _program(kind, sig, batch=len(members)),
+                     spec, (keys, vals, owner, *shared), {},
+                     rows=int(keys.size), batch=len(members))
     handle = _BatchHandle(len(members))
     for i, (a, b) in enumerate(members):
         _BATCH_SLOTS[id(b)] = _BatchSlot(
@@ -977,14 +986,148 @@ def _charge_two_level(ledger, topo, args, low, gmoved_init, post1, p3moved,
                                   tenant=args.tenant)
 
 
+def _dispatch(tracer, fn, spec: _PlanSpec, operands: tuple, ids: dict,
+              rows: int, **attrs) -> tuple:
+    """Run one replay program on host ``operands``; its outputs as host
+    arrays.  With tracing on, the stages are spans of their own, each
+    waited for: ``to_device`` (the inputs put on the device), ``jit_replay``
+    (dispatch until the outputs are ready on the device; ``compiled`` says
+    whether this program traced anew) and ``to_host`` (the outputs back).
+    With tracing off the program takes the host arrays and nothing waits
+    but the copy back."""
+    import jax
+
+    if not tracer.enabled:
+        with jax.enable_x64(True):
+            out = fn(spec, *operands)
+        return tuple(np.asarray(a) for a in out)
+    with jax.enable_x64(True):
+        with tracer.span("to_device", **ids, **attrs):
+            operands = jax.block_until_ready(jax.device_put(operands))
+        with tracer.span("jit_replay", **ids, rows=rows, **attrs) as sp:
+            traces = fn._cache_size()
+            out = jax.block_until_ready(fn(spec, *operands))
+            sp.set(compiled=fn._cache_size() > traces)
+    with tracer.span("to_host", **ids, **attrs):
+        return tuple(np.asarray(a) for a in out)
+
+
+def _charge_replay(ledger, topo, args: ShuffleArgs, low: JaxLowering,
+                   spec: _PlanSpec, arrs: tuple, per_w: list, rowb: int,
+                   batched: bool) -> list[tuple]:
+    """The reference executors' exact charge sequence, from the program's
+    flow counts; returns the per-level observed (level, pre, post) bytes."""
+    plan = args.plan
+    srcs, dsts = list(args.srcs), list(args.dsts)
+    observed: list[tuple] = []
+    if spec.template == "two_level":
+        gmoved_init, post1, p3moved = arrs[4:]
+        _charge_two_level(ledger, topo, args, low, gmoved_init, post1,
+                          p3moved, rowb)
+        return observed
+    lvl_moved, lvl_pre, lvl_post, gmoved = arrs[4:]
+    if spec.initial_comb:
+        for w, m in zip(srcs, per_w):     # network_aware local pre-combine
+            ledger.charge_combine(w, m.nbytes, tenant=args.tenant)
+    for li, ld in enumerate(plan.levels if spec.template != "bruck" else ()):
+        if not ld.eff_cost.beneficial:
+            continue
+        if not batched:
+            ledger.advance_epoch()        # the stage barrier (PLAN_STAGE)
+        staged = low.levels_staged[li]
+        for w, peers in staged:
+            wp = low.src_pos[w]
+            ledger.charge_transfers(
+                w,
+                np.fromiter((topo.crossing_level(w, n) for n in peers),
+                            dtype=np.int64, count=len(peers)),
+                np.fromiter(
+                    (int(lvl_moved[li, wp, low.src_pos[n]]) * rowb
+                     for n in peers), dtype=np.int64, count=len(peers)),
+                dsts=np.asarray(peers, dtype=np.int64), tenant=args.tenant)
+        for w, _peers in staged:
+            pre = int(lvl_pre[li, low.src_pos[w]]) * rowb
+            post = int(lvl_post[li, low.src_pos[w]]) * rowb
+            if args.comb_fn is not None:
+                ledger.charge_combine(w, pre, tenant=args.tenant)
+            observed.append((ld.level, pre, post))
+
+    if spec.template == "bruck":
+        _charge_bruck(ledger, topo, args, low, gmoved, rowb)
+        return observed
+    if spec.template in ("vanilla_push", "network_aware"):
+        for w in srcs:                    # push: the sender pays
+            wp = low.src_pos[w]
+            ledger.charge_transfers(
+                w,
+                np.fromiter((topo.crossing_level(w, d) for d in dsts),
+                            dtype=np.int64, count=len(dsts)),
+                gmoved[wp].astype(np.int64) * rowb,
+                dsts=np.asarray(dsts, dtype=np.int64),
+                tenant=args.tenant)
+        fetch_order = {d: srcs for d in dsts}
+        charge_receiver = False
+    elif spec.template == "vanilla_pull":
+        fetch_order = {d: srcs for d in dsts}
+        charge_receiver = True
+    else:                                 # coordinated: ring order, receiver pays
+        n = len(srcs)
+        fetch_order = {d: [srcs[(srcs.index(d) - t) % n]
+                           for t in range(n)] for d in dsts}
+        charge_receiver = True
+    for d in dsts:
+        dp = low.dst_pos[d]
+        order = fetch_order[d]
+        if charge_receiver:
+            ledger.charge_transfers(
+                d,
+                np.fromiter((topo.crossing_level(s, d) for s in order),
+                            dtype=np.int64, count=len(order)),
+                np.fromiter((int(gmoved[low.src_pos[s], dp]) * rowb
+                             for s in order), dtype=np.int64,
+                            count=len(order)),
+                dsts=np.full(len(order), d, dtype=np.int64),
+                tenant=args.tenant)
+        if args.comb_fn is not None:
+            ledger.charge_combine(d, int(gmoved[:, dp].sum()) * rowb,
+                                  tenant=args.tenant)
+    return observed
+
+
+def _owner_merge(ledger, topo, args: ShuffleArgs, out_bufs: dict) -> None:
+    """The skew owner-merge stage, in place on ``out_bufs``: scattered hot
+    rows travel back to their base destination — Python-side, mirroring the
+    vectorized replay exactly."""
+    merge = owner_merge_plan(args.plan.skew, args.part_fn, tuple(args.dsts))
+    inbox: dict[int, list] = {}
+    for owner_w, (owned_keys, sharers) in merge.items():
+        got = []
+        for s in sharers:
+            hit = np.isin(out_bufs[s].keys, owned_keys)
+            rows = out_bufs[s].take(np.nonzero(hit)[0])
+            out_bufs[s] = out_bufs[s].take(np.nonzero(~hit)[0])
+            ledger.charge_transfer(s, topo.crossing_level(s, owner_w),
+                                   rows.nbytes, dst=owner_w,
+                                   tenant=args.tenant)
+            got.append(rows)
+        inbox[owner_w] = got
+    for owner_w, got in inbox.items():
+        batch = Msgs.concat([out_bufs[owner_w]] + got)
+        if args.comb_fn is not None:
+            ledger.charge_combine(owner_w, batch.nbytes, tenant=args.tenant)
+            out_bufs[owner_w] = combine_msgs(args.comb_fn, batch)
+        else:
+            out_bufs[owner_w] = batch
+
+
 def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
                  low: JaxLowering, manager,
                  batch_slot: "_BatchSlot | None" = None) -> ShuffleResult:
-    import jax
-
     plan = args.plan
     topo = cluster.topology
     ledger = cluster.ledger
+    tracer = cluster.obs.tracer
+    ids = {"shuffle_id": args.shuffle_id, "tenant": args.tenant}
     srcs, dsts = list(args.srcs), list(args.dsts)
     participants = sorted(set(srcs) | set(dsts))
     width = next((m.width for m in bufs.values() if m.n), 1)
@@ -997,142 +1140,47 @@ def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
             manager.record_start(w, args.shuffle_id, args.template_id,
                                  tenant=args.tenant)
     before = ledger.snapshot()
-    observed: list[tuple] = []
 
     # ---- the compiled data plane ------------------------------------------
-    per_w = [bufs.get(w, Msgs.empty(width)) for w in srcs]
-    keys = np.concatenate([m.keys for m in per_w])
-    vals = np.concatenate([np.ascontiguousarray(m.vals) for m in per_w])
+    with tracer.span("stage_inputs", **ids):
+        per_w = [bufs.get(w, Msgs.empty(width)) for w in srcs]
+        keys = np.concatenate([m.keys for m in per_w])
+        vals = np.concatenate([np.ascontiguousarray(m.vals) for m in per_w])
+        if batch_slot is None:
+            owner = np.concatenate([np.full(m.n, low.src_pos[w], np.int32)
+                                    for w, m in zip(srcs, per_w)])
+            kind, shared = _program_inputs(spec, low)
+            fn = _program(kind, (spec, keys.shape, vals.shape,
+                                 tuple(a.shape for a in shared)))
     if batch_slot is not None:
         arrs = batch_slot.outputs         # this member's slice of the batch
     else:
-        owner = np.concatenate([np.full(m.n, low.src_pos[w], np.int32)
-                                for w, m in zip(srcs, per_w)])
-        kind, shared = _program_inputs(spec, low)
-        sig = (spec, keys.shape, vals.shape, tuple(a.shape for a in shared))
-        tracer = cluster.obs.tracer
-        jit_sp = tracer.span(
-            "jit_replay", shuffle_id=args.shuffle_id, tenant=args.tenant,
-            rows=int(keys.shape[0]), traces_before=replay_cache_size(),
-        ) if tracer.enabled else None
-        with jax.enable_x64(True):
-            out = _program(kind, sig)(spec, keys, vals, owner, *shared)
-        if jit_sp is not None:
-            jit_sp.end(traces_after=replay_cache_size())
-        arrs = tuple(np.asarray(a) for a in out)
+        arrs = _dispatch(tracer, fn, spec, (keys, vals, owner, *shared), ids,
+                         rows=int(keys.shape[0]))
 
-    # ---- ledger replay: the reference executors' exact charge sequence ----
-    if spec.template == "two_level":
-        (f_keys, f_vals, f_owner, f_alive, gmoved_init, post1, p3moved) = arrs
-        _charge_two_level(ledger, topo, args, low, gmoved_init, post1,
-                          p3moved, rowb)
-    else:
-        (f_keys, f_vals, f_owner, f_alive,
-         lvl_moved, lvl_pre, lvl_post, gmoved) = arrs
-        if spec.initial_comb:
-            for w, m in zip(srcs, per_w):  # network_aware local pre-combine
-                ledger.charge_combine(w, m.nbytes, tenant=args.tenant)
-        for li, ld in enumerate(plan.levels if spec.template != "bruck" else ()):
-            if not ld.eff_cost.beneficial:
-                continue
-            if batch_slot is None:
-                ledger.advance_epoch()    # the stage barrier (PLAN_STAGE)
-            staged = low.levels_staged[li]
-            for w, peers in staged:
-                wp = low.src_pos[w]
-                ledger.charge_transfers(
-                    w,
-                    np.fromiter((topo.crossing_level(w, n) for n in peers),
-                                dtype=np.int64, count=len(peers)),
-                    np.fromiter(
-                        (int(lvl_moved[li, wp, low.src_pos[n]]) * rowb
-                         for n in peers), dtype=np.int64, count=len(peers)),
-                    dsts=np.asarray(peers, dtype=np.int64), tenant=args.tenant)
-            for w, _peers in staged:
-                pre = int(lvl_pre[li, low.src_pos[w]]) * rowb
-                post = int(lvl_post[li, low.src_pos[w]]) * rowb
-                if args.comb_fn is not None:
-                    ledger.charge_combine(w, pre, tenant=args.tenant)
-                observed.append((ld.level, pre, post))
+    with tracer.span("ledger_replay", **ids):
+        observed = _charge_replay(ledger, topo, args, low, spec, arrs, per_w,
+                                  rowb, batched=batch_slot is not None)
 
-        if spec.template == "bruck":
-            _charge_bruck(ledger, topo, args, low, gmoved, rowb)
-        else:
-            if spec.template in ("vanilla_push", "network_aware"):
-                for w in srcs:            # push: the sender pays
-                    wp = low.src_pos[w]
-                    ledger.charge_transfers(
-                        w,
-                        np.fromiter((topo.crossing_level(w, d) for d in dsts),
-                                    dtype=np.int64, count=len(dsts)),
-                        gmoved[wp].astype(np.int64) * rowb,
-                        dsts=np.asarray(dsts, dtype=np.int64),
-                        tenant=args.tenant)
-                fetch_order = {d: srcs for d in dsts}
-                charge_receiver = False
-            elif spec.template == "vanilla_pull":
-                fetch_order = {d: srcs for d in dsts}
-                charge_receiver = True
-            else:                         # coordinated: ring order, receiver pays
-                n = len(srcs)
-                fetch_order = {d: [srcs[(srcs.index(d) - t) % n]
-                                   for t in range(n)] for d in dsts}
-                charge_receiver = True
-            for d in dsts:
-                dp = low.dst_pos[d]
-                order = fetch_order[d]
-                if charge_receiver:
-                    ledger.charge_transfers(
-                        d,
-                        np.fromiter((topo.crossing_level(s, d) for s in order),
-                                    dtype=np.int64, count=len(order)),
-                        np.fromiter((int(gmoved[low.src_pos[s], dp]) * rowb
-                                     for s in order), dtype=np.int64,
-                                    count=len(order)),
-                        dsts=np.full(len(order), d, dtype=np.int64),
-                        tenant=args.tenant)
-                if args.comb_fn is not None:
-                    ledger.charge_combine(d, int(gmoved[:, dp].sum()) * rowb,
-                                          tenant=args.tenant)
-
-    out_bufs: dict[int, Msgs] = {}
-    for d in dsts:
-        mask = (f_owner == low.dst_pos[d]) & f_alive
-        out_bufs[d] = Msgs(f_keys[mask],
-                           f_vals[mask].reshape(-1, width))
-    if (kernel_plane_enabled() and spec.comb == "sum" and not spec.skew
-            and spec.template not in ("bruck", "two_level")):
-        # Pallas plane (opt-in): same routing and key sets, payloads
-        # re-folded on the MXU kernels (float32 accumulation — see
-        # set_kernel_plane)
-        for d, (kk, vv) in zip(dsts,
-                               kernel_global_stage(args.part_fn, keys, vals,
-                                                   len(dsts))):
-            out_bufs[d] = Msgs(kk, vv.reshape(-1, width))
+    with tracer.span("split_outputs", **ids):
+        f_keys, f_vals, f_owner, f_alive = arrs[:4]
+        out_bufs: dict[int, Msgs] = {}
+        for d in dsts:
+            mask = (f_owner == low.dst_pos[d]) & f_alive
+            out_bufs[d] = Msgs(f_keys[mask],
+                               f_vals[mask].reshape(-1, width))
+        if (kernel_plane_enabled() and spec.comb == "sum" and not spec.skew
+                and spec.template not in ("bruck", "two_level")):
+            # Pallas plane (opt-in): same routing and key sets, payloads
+            # re-folded on the MXU kernels (float32 accumulation — see
+            # set_kernel_plane)
+            for d, (kk, vv) in zip(dsts,
+                                   kernel_global_stage(args.part_fn, keys,
+                                                       vals, len(dsts))):
+                out_bufs[d] = Msgs(kk, vv.reshape(-1, width))
     if spec.skew:
-        # the owner-merge stage: scattered hot rows travel back to their base
-        # destination — Python-side, mirroring the vectorized replay exactly
-        merge = owner_merge_plan(plan.skew, args.part_fn, tuple(dsts))
-        inbox: dict[int, list] = {}
-        for owner_w, (owned_keys, sharers) in merge.items():
-            got = []
-            for s in sharers:
-                hit = np.isin(out_bufs[s].keys, owned_keys)
-                rows = out_bufs[s].take(np.nonzero(hit)[0])
-                out_bufs[s] = out_bufs[s].take(np.nonzero(~hit)[0])
-                ledger.charge_transfer(s, topo.crossing_level(s, owner_w),
-                                       rows.nbytes, dst=owner_w,
-                                       tenant=args.tenant)
-                got.append(rows)
-            inbox[owner_w] = got
-        for owner_w, got in inbox.items():
-            batch = Msgs.concat([out_bufs[owner_w]] + got)
-            if args.comb_fn is not None:
-                ledger.charge_combine(owner_w, batch.nbytes,
-                                      tenant=args.tenant)
-                out_bufs[owner_w] = combine_msgs(args.comb_fn, batch)
-            else:
-                out_bufs[owner_w] = batch
+        with tracer.span("owner_merge", **ids):
+            _owner_merge(ledger, topo, args, out_bufs)
     if batch_slot is None:
         ledger.advance_epoch()            # shuffle completion is a barrier
     else:

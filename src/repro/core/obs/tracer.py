@@ -15,6 +15,11 @@ Two tracer implementations share the same surface:
   (``capacity`` most recent spans; older spans fall off, ``dropped`` counts
   them).  ``spans()`` filters by shuffle id / name; ``export_jsonl`` dumps
   the buffer one span per line for offline tooling (the doctor CLI).
+  Where JAX is already imported, every span it opens is mirrored as a
+  ``jax.profiler.TraceAnnotation("teshu.<name>")`` opened and closed with
+  it, so a profiler trace holds the same interval on the device trace's
+  clock (zero-duration points are not mirrored).  The recorder never
+  imports JAX itself.
 * :class:`NullTracer` — the disabled path, and the default on every
   :class:`~repro.core.primitives.LocalCluster`.  ``span()`` returns a shared
   no-op object and performs **no timestamp syscalls and no allocation**, so
@@ -25,13 +30,15 @@ Spans support both ``with tracer.span(...)`` (nests via a thread-local stack
 and survives exceptions — the error is recorded as an attr) and manual
 ``sp = tracer.span(...); ...; sp.end()`` for loop bodies where a ``with``
 block would force deep re-indentation.  A span abandoned without ``end()``
-is simply never recorded.
+is simply never recorded (its mirrored annotation closes when the span is
+collected).
 """
 from __future__ import annotations
 
 import collections
 import itertools
 import json
+import sys
 import threading
 import time
 
@@ -89,7 +96,7 @@ class Span:
     """One live span; becomes a recorded dict when :meth:`end` fires."""
 
     __slots__ = ("_tracer", "span_id", "parent_id", "name", "shuffle_id",
-                 "tenant", "attrs", "t0", "t1", "_entered")
+                 "tenant", "attrs", "t0", "t1", "_entered", "_mirror")
 
     def __init__(self, tracer: "FlightRecorder", name: str,
                  shuffle_id: int | None, tenant: str | None, attrs: dict):
@@ -103,6 +110,7 @@ class Span:
         self.t0 = time.monotonic()
         self.t1: float | None = None
         self._entered = False
+        self._mirror = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -113,6 +121,9 @@ class Span:
         if attrs:
             self.attrs.update(attrs)
         self.t1 = time.monotonic()
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
         self._tracer._record(self)
 
     def __enter__(self) -> "Span":
@@ -162,7 +173,12 @@ class FlightRecorder:
         """Open a span.  Use as a context manager (nests under the thread's
         current span) or call ``.end()`` manually (reads the current parent at
         creation but never occupies the stack)."""
-        return Span(self, name, shuffle_id, tenant, attrs)
+        sp = Span(self, name, shuffle_id, tenant, attrs)
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            sp._mirror = jax.profiler.TraceAnnotation("teshu." + name)
+            sp._mirror.__enter__()
+        return sp
 
     def point(self, name: str, *, shuffle_id: int | None = None,
               tenant: str | None = None, **attrs) -> None:

@@ -986,18 +986,22 @@ class TeShuCluster:
             tenant=tenant, balance=balance,
             skew_threshold=client.knob("skew_threshold", skew_threshold))
 
-        key = plan_key(template_id, self.topology, args.srcs, args.dsts,
-                       stats_signature(bufs, part_fn, comb_fn, rate,
-                                       balance=balance,
-                                       skew_threshold=args.skew_threshold,
-                                       streaming=streaming, stream=chunk),
-                       epoch=self._epoch())
         tracer = self.obs.tracer
         # the root span: a no-op _NULL_SPAN when tracing is off, a real
         # context-managed span (children nest via the thread-local stack) when on
         with tracer.span("shuffle", shuffle_id=args.shuffle_id, tenant=tenant,
                          template=template_id, execution=execution,
                          executor=executor) as root:
+            # ---- plan key: the stats signature pass over the buffers --------
+            with tracer.span("plan_key", shuffle_id=args.shuffle_id,
+                             tenant=tenant):
+                key = plan_key(
+                    template_id, self.topology, args.srcs, args.dsts,
+                    stats_signature(bufs, part_fn, comb_fn, rate,
+                                    balance=balance,
+                                    skew_threshold=args.skew_threshold,
+                                    streaming=streaming, stream=chunk),
+                    epoch=self._epoch())
             # ---- plan lookup (+ cache explainability) -----------------------
             lk = tracer.span("plan_lookup", shuffle_id=args.shuffle_id,
                              tenant=tenant) if tracer.enabled else None

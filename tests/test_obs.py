@@ -35,6 +35,7 @@ from repro.launch import doctor
 
 WORKERS = list(range(8))
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_twice(sv, template, bufs, workers, **kw):
@@ -384,17 +385,243 @@ def test_tracing_on_builds_span_tree(tmp_path):
 
 def test_tracing_jax_spans_lower_and_replay():
     sv = service_for("jax", tracing=True)
-    bufs = make_bufs(WORKERS, "uniform", n=281)
+    # a row count and width no other test of this file replays, so the
+    # first replay here is the program's first trace
+    bufs = make_bufs(WORKERS, "uniform", n=281, width=3)
     hit = _run_twice(sv, "vanilla_push", bufs, WORKERS, comb_fn=SUM,
                      shuffle_id=911)
     assert hit.engine == "jax"
     by_name = {s["name"]: s for s in sv.spans(911)}
     assert by_name["exec"]["attrs"]["engine"] == "jax"
     assert by_name["lower"]["attrs"]["declined"] is False
-    assert by_name["jit_replay"]["attrs"]["rows"] > 0
-    # steady-state replay: the trace cache did not grow on this hit
-    jr = by_name["jit_replay"]["attrs"]
-    assert jr["traces_after"] >= jr["traces_before"]
+    assert by_name["jit_replay"]["attrs"]["rows"] == 8 * 281
+    assert by_name["jit_replay"]["attrs"]["compiled"] is True
+    # steady-state replay: this program's trace cache did not grow
+    sv.shuffle("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+               comb_fn=SUM, shuffle_id=911)
+    jit = [s for s in sv.spans(911) if s["name"] == "jit_replay"]
+    assert [s["attrs"]["compiled"] for s in jit] == [True, False]
+
+
+EXEC_STAGES = ("stage_inputs", "to_device", "jit_replay", "to_host",
+               "ledger_replay", "split_outputs")
+
+
+def _skewed_case():
+    """A Zipf workload whose plan splits hot keys (the owner-merge runs)."""
+    topo = datacenter(4, 2, 1)
+    bufs = make_bufs(WORKERS, "zipf", n=8000, key_space=500, width=1)
+    return topo, bufs, {"comb_fn": SUM, "balance": "auto"}
+
+
+def _children(spans, parent):
+    return [s for s in spans if s["parent_id"] == parent["span_id"]]
+
+
+def _within(child, parent):
+    return parent["t0"] <= child["t0"] <= child["t1"] <= parent["t1"]
+
+
+def test_jax_exec_splits_into_stage_spans():
+    sv = service_for("jax", tracing=True)
+    bufs = make_bufs(WORKERS, "uniform", n=331)
+    hit = _run_twice(sv, "vanilla_push", bufs, WORKERS, comb_fn=SUM,
+                     shuffle_id=912)
+    assert hit.engine == "jax"
+    spans = sv.spans(912)
+    (root,) = [s for s in spans if s["name"] == "shuffle"
+               and s["attrs"]["engine"] == "jax"]
+    kids = {s["name"]: s for s in _children(spans, root)}
+    assert {"plan_key", "plan_lookup", "exec"} <= set(kids)
+    assert all(_within(s, root) for s in kids.values())
+    # the stats signature pass runs inside the root span, before the lookup
+    assert kids["plan_key"]["t1"] <= kids["plan_lookup"]["t0"]
+    exe = kids["exec"]
+    stages = _children(spans, exe)
+    assert [s["name"] for s in sorted(stages, key=lambda s: s["t0"])] == \
+        list(EXEC_STAGES)
+    assert all(_within(s, exe) for s in stages)
+    assert not any(s["name"] == "owner_merge" for s in spans)
+
+
+def test_skewed_jax_exec_has_an_owner_merge_span():
+    topo, bufs, kw = _skewed_case()
+    sv = TeShuService(topo, executor="jax", tracing=True)
+    hit = _run_twice(sv, "vanilla_push", bufs, WORKERS, shuffle_id=913, **kw)
+    assert hit.engine == "jax"
+    assert dict(hit.decisions)["rebalance"].triggered
+    spans = sv.spans(913)
+    exe = [s for s in spans if s["name"] == "exec"][-1]
+    stages = sorted(_children(spans, exe), key=lambda s: s["t0"])
+    assert [s["name"] for s in stages] == list(EXEC_STAGES) + ["owner_merge"]
+    assert all(_within(s, exe) for s in stages)
+
+
+@pytest.mark.parametrize("case", ["vanilla_push", "network_aware", "skewed"])
+def test_jax_outputs_identical_with_tracing_on_and_off(case):
+    if case == "skewed":
+        topo, bufs, kw = _skewed_case()
+        template = "vanilla_push"
+    else:
+        topo, bufs, kw = None, make_bufs(WORKERS, "uniform", n=337), {
+            "comb_fn": SUM}
+        template = case
+    out = {}
+    for tracing in (False, True):
+        sv = service_for("jax", topo, tracing=tracing)
+        res = _run_twice(sv, template, bufs, WORKERS, **kw)
+        assert res.engine == "jax"
+        assert bool(sv.spans()) == tracing
+        out[tracing] = res
+    off, on = out[False], out[True]
+    assert off.bufs.keys() == on.bufs.keys()
+    for d in off.bufs:
+        assert np.array_equal(off.bufs[d].keys, on.bufs[d].keys)
+        assert np.array_equal(off.bufs[d].vals, on.bufs[d].vals)
+    for lane in ("total_bytes", "bytes_per_level", "recv_bytes_per_worker",
+                 "bytes_per_tenant"):
+        assert off.stats[lane] == on.stats[lane]
+
+
+def test_tracing_off_builds_no_annotation_and_adds_no_transfer(monkeypatch):
+    import jax
+
+    counts = {"annotation": 0, "device_put": 0, "block_until_ready": 0}
+
+    class CountedAnnotation(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            counts["annotation"] += 1
+            super().__init__(*a, **kw)
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            counts[name] += 1
+            return fn(*a, **kw)
+        return wrapper
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountedAnnotation)
+    monkeypatch.setattr(jax, "device_put", counted("device_put",
+                                                   jax.device_put))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        counted("block_until_ready", jax.block_until_ready))
+    sv = service_for("jax")
+    bufs = make_bufs(WORKERS, "uniform", n=347)
+    assert _run_twice(sv, "vanilla_push", bufs, WORKERS,
+                      comb_fn=SUM).engine == "jax"
+    assert sv.spans() == []
+    assert counts == {"annotation": 0, "device_put": 0,
+                      "block_until_ready": 0}
+    # the same replay traced: each counter sees its calls
+    sv.enable_tracing()
+    for _ in range(2):
+        sv.shuffle("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                   comb_fn=SUM)
+    assert counts["annotation"] == len(sv.spans())
+    assert counts["device_put"] == 2
+    assert counts["block_until_ready"] == 4
+    # operands on the device key one more entry of the program's cache than
+    # host arrays did (a retrace, not a compile); after that it is a hit
+    jit = [s for s in sv.spans() if s["name"] == "jit_replay"]
+    assert jit[-1]["attrs"]["compiled"] is False
+
+
+def test_batched_dispatch_records_its_device_stages():
+    """``run_pending``'s one vmapped dispatch records to_device, jit_replay
+    and to_host with the member count; its members' execs have no device
+    stage of their own."""
+    cl = TeShuCluster(make_topology(), execution="auto", executor="jax",
+                      tracing=True)
+    tenants = [cl.tenant(f"t{i}") for i in range(3)]
+    bufs = make_bufs(WORKERS, "uniform", n=349)
+    for t in tenants:                               # plan + trace per tenant
+        _run_twice(t, "vanilla_push", bufs, WORKERS, comb_fn=SUM)
+    cl.obs.tracer.clear()
+    tickets = [t.submit("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                        comb_fn=SUM) for t in tenants]
+    results = cl.run_pending()
+    assert all(results[tk].batched for tk in tickets)
+    spans = cl.spans()
+    device = [s for s in spans if s["name"] in ("to_device", "jit_replay",
+                                                "to_host")]
+    assert [s["name"] for s in device] == ["to_device", "jit_replay",
+                                           "to_host"]
+    assert all(s["attrs"]["batch"] == 3 for s in device)
+    assert device[1]["attrs"]["rows"] == 3 * 8 * 349
+    assert [s["name"] for s in spans if s["name"] == "exec"] == ["exec"] * 3
+    assert sum(s["name"] == "ledger_replay" for s in spans) == 3
+
+
+def _profiled(tmp_path, fn):
+    """Host events of a CPU profiler trace taken around ``fn()``."""
+    import glob
+
+    import jax
+
+    from chipbench import trace
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    return trace.read_xspace(path)[1]
+
+
+def test_spans_are_mirrored_into_the_profiler_trace(tmp_path, monkeypatch):
+    monkeypatch.syspath_prepend(ROOT)
+    sv = service_for("jax", tracing=True)
+    bufs = make_bufs(WORKERS, "uniform", n=353)
+    _run_twice(sv, "vanilla_push", bufs, WORKERS, comb_fn=SUM)
+    host = _profiled(tmp_path, lambda: sv.shuffle(
+        "vanilla_push", copy_bufs(bufs), WORKERS, WORKERS, comb_fn=SUM,
+        shuffle_id=914))
+    events = {}
+    for name, t0, t1 in host:
+        if name.startswith("teshu."):
+            events.setdefault(name, []).append((t0, t1))
+    recorded = {"teshu." + s["name"] for s in sv.spans(914)}
+    assert {"teshu.shuffle", "teshu.exec", "teshu.jit_replay"} <= recorded
+    assert set(events) == recorded
+    (sh,), (ex,), (jr,) = (events[f"teshu.{n}"]
+                           for n in ("shuffle", "exec", "jit_replay"))
+    assert sh[0] <= ex[0] <= jr[0] <= jr[1] <= ex[1] <= sh[1]
+    # the same intervals as the recorder's spans, on the profiler's clock
+    spans = {s["name"]: s for s in sv.spans(914)}
+    for name, (t0, t1) in (("shuffle", sh), ("exec", ex), ("jit_replay", jr)):
+        dur = spans[name]["t1"] - spans[name]["t0"]
+        assert (t1 - t0) * 1e-9 == pytest.approx(dur, rel=0.05, abs=2e-4)
+
+
+def test_mirrored_spans_ended_out_of_order(tmp_path, monkeypatch):
+    """Manual ``end()`` need not follow the order spans were opened in: each
+    annotation keeps its own interval."""
+    import time
+
+    monkeypatch.syspath_prepend(ROOT)
+    import jax  # noqa: F401  (the recorder mirrors only where jax is loaded)
+
+    tr = FlightRecorder()
+
+    def overlapping():
+        a = tr.span("outer_first")
+        time.sleep(0.01)
+        b = tr.span("inner_last")
+        time.sleep(0.01)
+        a.end()
+        time.sleep(0.01)
+        b.end()
+        tr.point("instant")
+    host = _profiled(tmp_path, overlapping)
+    got = {n: (t0, t1) for n, t0, t1 in host if n.startswith("teshu.")}
+    assert set(got) == {"teshu.outer_first", "teshu.inner_last"}
+    (a0, a1), (b0, b1) = got["teshu.outer_first"], got["teshu.inner_last"]
+    assert a0 < b0 < a1 < b1
+    spans = {s["name"]: s for s in tr.spans()}
+    for name, (t0, t1) in (("outer_first", (a0, a1)),
+                           ("inner_last", (b0, b1))):
+        dur = spans[name]["t1"] - spans[name]["t0"]
+        assert (t1 - t0) * 1e-9 == pytest.approx(dur, rel=0.05, abs=2e-4)
 
 
 def test_streaming_metrics_and_spans():
