@@ -26,12 +26,15 @@ becomes a whole-array operation:
   ``coordinated``), so the physical array order after the sort IS the
   byte-order the numpy executor concatenates in.
 * **COMB** stable-sorts each owner's segment by key and folds equal-key
-  rows with a sequential :func:`jax.lax.scan` — an explicit left fold in
-  element order, which is exactly the ``ufunc.at`` contract of
-  :class:`repro.core.messages.Combiner` — so float64 SUM results are
-  *bit-identical* to both other executors.  Combined-away rows are marked
-  dead and sort to the end; row capacity stays ``N`` throughout, keeping
-  every shape static.
+  rows in rounds by rank within a segment: round 0 seeds every segment with
+  its first row, round ``j`` folds every segment's row ``j`` into it.  Each
+  segment is still an explicit left fold in element order, which is
+  exactly the ``ufunc.at`` contract of
+  :class:`repro.core.messages.Combiner`, so float64 SUM results are
+  *bit-identical* to both other executors; the serial depth is the longest
+  segment, not the row count.  Combined-away rows are marked dead and sort
+  to the end; row capacity stays ``N`` throughout, keeping every shape
+  static.
 
 Irregular templates lower too.  ``bruck``'s log-round piece routing is
 simulated symbolically at lower time (pieces move whole and never split, so
@@ -325,20 +328,78 @@ def _skew_slot(keys, owner, alive, base_slot, ns, hot_keys, share_slots,
         return jnp.where(is_hot, share.astype(jnp.int32), base_slot)
 
 
+_FOLD_GROWTH = 8     # a fold phase's first round is 8x the previous phase's
+
+
+def _segment_fold(op, vals, is_start):
+    """The left fold of every segment (a run of rows opened by an
+    ``is_start`` row), in rounds by rank within a segment.
+
+    Segments are laid out longest first, so the segments still folding at
+    round ``j`` (those longer than ``j``) are a prefix of that order.  Round
+    0 seeds each segment's accumulator with its first row; round ``j``
+    gathers every live segment's row ``j`` and folds it in with ``op``.
+    Each segment thus computes ``op(...op(op(v0, v1), v2)..., v_last)``,
+    the same operations in the same order as a row-serial scan, while the
+    serial depth is the longest segment rather than the row count.  From
+    round ``j0`` on, at most ``n // (j0 + 1)`` segments are live, so the
+    rounds run in phases (first rounds 1, 8, 64, ...), each one loop over a
+    static window of that many accumulators.  Payload columns fold as
+    ``[d, n]``, rows along the lanes.
+
+    Returns the folded rows, each segment's result at its last row (the
+    other rows are never read), and the longest segment's length: the
+    rounds the fold ran.
+    """
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = vals.shape[0]
+    # segment k starts at starts[k] (a sort: the v5e compiler runs out of
+    # VMEM for a cumulative sum over millions of rows)
+    k = jnp.arange(n, dtype=jnp.int32)
+    nseg = is_start.sum(dtype=jnp.int32)
+    starts = jnp.argsort(~is_start, stable=True).astype(jnp.int32)
+    nxt = jnp.where(k + 1 < nseg, jnp.roll(starts, -1), n)
+    lens = jnp.where(k < nseg, nxt - starts, 0)
+    first = jnp.where(k < nseg, starts, n)       # padding: first n, length 0
+    neg_lens, first = lax.sort((-lens, first), num_keys=1)
+    lens = -neg_lens
+    longest = lens[0]
+    cols = vals.T
+    acc = cols.at[:, first].get(mode="clip")
+    j0 = 1
+    while j0 < n:
+        w = n // (j0 + 1)
+
+        def fold_round(j, acc_w, first_w=first[:w], lens_w=lens[:w]):
+            v = cols.at[:, first_w + j].get(mode="clip")
+            return jnp.where(lens_w > j, op(acc_w, v), acc_w)
+
+        acc = acc.at[:, :w].set(lax.fori_loop(
+            j0, jnp.minimum(j0 * _FOLD_GROWTH, longest), fold_round,
+            acc[:, :w]))
+        j0 *= _FOLD_GROWTH
+    # each segment's last row reads its accumulator
+    last = jnp.where(lens > 0, first + lens - 1, n)
+    slot = jnp.zeros((n,), jnp.int32).at[last].set(k, mode="drop")
+    return acc[:, slot].T, longest
+
+
 def _combine(comb: str, keys, vals, owner, alive, participate, sentinel: int):
     """Per-owner equal-key fold, bit-identical to messages.Combiner.
 
     Stable lexsort by (owner, key) — non-participating rows keep their
     relative order (their sort key is constant and owners never mix
-    participation) — then a sequential lax.scan left fold over rows:
-    each segment is seeded with its first row and the rest fold in element
-    order, which is numpy's ``ufunc.at`` contract exactly.  Non-segment-end
-    rows die (owner keeps its value; every later sort sends dead rows to
-    the end via the alive mask).
+    participation) — then :func:`_segment_fold` over the equal-(owner, key)
+    segments: each is seeded with its first row and the rest fold in
+    element order, which is numpy's ``ufunc.at`` contract exactly.
+    Non-segment-end rows die (owner keeps its value; every later sort sends
+    dead rows to the end via the alive mask).  Also returns the fold's
+    rounds (the longest segment).
     """
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     with jax.named_scope("teshu/comb_sort"):
         folds = participate & alive
@@ -352,23 +413,18 @@ def _combine(comb: str, keys, vals, owner, alive, participate, sentinel: int):
                      & (keys == jnp.roll(keys, 1))).at[0].set(False)
         is_start = ~(prev_same & folds)
     op = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[comb]
-
-    def fold(acc, x):
-        v, start = x
-        acc = jnp.where(start, v, op(acc, v))
-        return acc, acc
-
     with jax.named_scope("teshu/comb_fold"):
-        _, folded = lax.scan(fold, jnp.zeros_like(vals[0]), (vals, is_start))
+        folded, rounds = _segment_fold(op, vals, is_start)
         seg_end = jnp.concatenate([is_start[1:], jnp.ones((1,), bool)])
-    return keys, folded, owner, alive & seg_end
+    return keys, folded, owner, alive & seg_end, rounds
 
 
 def _replay_impl(spec: _PlanSpec, keys, vals, owner,
                  gsize, slot_map, rank_map, active, global_rank,
                  hot_keys, share_slots, share_len):
     """The rolled-scan replay shared by the four regular templates and (with
-    zero levels plus a simulated global_rank) bruck."""
+    zero levels plus a simulated global_rank) bruck.  The last output is
+    the fold rounds of every COMB it ran."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -376,12 +432,13 @@ def _replay_impl(spec: _PlanSpec, keys, vals, owner,
     ns, ndst = spec.ns, spec.ndst
     n = keys.shape[0]
     alive = jnp.ones((n,), bool)
+    rounds = jnp.int32(0)
     if spec.initial_comb:
-        keys, vals, owner, alive = _combine(
+        keys, vals, owner, alive, rounds = _combine(
             spec.comb, keys, vals, owner, alive, alive, ns)
 
     def level_body(carry, xs):
-        keys, vals, owner, alive = carry
+        keys, vals, owner, alive, rounds = carry
         g_l, slot_l, rank_l, act = xs
         oc = jnp.minimum(owner, ns - 1)
         g = g_l[oc]
@@ -403,18 +460,20 @@ def _replay_impl(spec: _PlanSpec, keys, vals, owner,
             owner2, alive2 = new_owner[perm], alive[perm]
         staged_owner = act & (g_l[jnp.minimum(owner2, ns - 1)] > 1)
         if spec.comb is not None:
-            keys2, vals2, owner2, alive2 = _combine(
+            keys2, vals2, owner2, alive2, r = _combine(
                 spec.comb, keys2, vals2, owner2, alive2,
                 staged_owner & alive2, ns)
+            rounds = rounds + r
         post_row = (alive2 & act
                     & (g_l[jnp.minimum(owner2, ns - 1)] > 1))
         post = jnp.zeros((ns,), jnp.int32).at[
             jnp.minimum(owner2, ns - 1)].add(post_row.astype(jnp.int32))
-        return (keys2, vals2, owner2, alive2), (moved, moved.sum(0), post)
+        return ((keys2, vals2, owner2, alive2, rounds),
+                (moved, moved.sum(0), post))
 
-    (keys, vals, owner, alive), (lvl_moved, lvl_pre, lvl_post) = lax.scan(
-        level_body, (keys, vals, owner, alive),
-        (gsize, slot_map, rank_map, active))
+    (keys, vals, owner, alive, rounds), (lvl_moved, lvl_pre, lvl_post) = \
+        lax.scan(level_body, (keys, vals, owner, alive, rounds),
+                 (gsize, slot_map, rank_map, active))
 
     # ---- global exchange: every alive row repartitions over the dsts ----
     oc = jnp.minimum(owner, ns - 1)
@@ -434,9 +493,11 @@ def _replay_impl(spec: _PlanSpec, keys, vals, owner,
         keys, vals = keys[perm], vals[perm]
         owner, alive = new_owner[perm], alive[perm]
     if spec.comb is not None:
-        keys, vals, owner, alive = _combine(
+        keys, vals, owner, alive, r = _combine(
             spec.comb, keys, vals, owner, alive, alive, ndst)
-    return keys, vals, owner, alive, lvl_moved, lvl_pre, lvl_post, gmoved
+        rounds = rounds + r
+    return (keys, vals, owner, alive, lvl_moved, lvl_pre, lvl_post, gmoved,
+            rounds)
 
 
 def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
@@ -449,7 +510,8 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
     keys, so the threaded re-COMB is an order-preserving identity) — and
     phase 3 delivers within the destination group.  Each exchange is one
     stable sort on the grid's exact mailbox concat order: (receiver, sender
-    member index, slot).  Returns the phase flow counts the ledger replays.
+    member index, slot).  Returns the phase flow counts the ledger replays,
+    then the fold rounds of its COMBs.
     """
     import jax
     import jax.numpy as jnp
@@ -470,8 +532,9 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
         perm = jnp.argsort(ck, stable=True)
         keys, vals, owner, alive = (keys[perm], vals[perm], w1[perm],
                                     alive[perm])
+    rounds = jnp.int32(0)
     if spec.comb is not None:
-        keys, vals, owner, alive = _combine(
+        keys, vals, owner, alive, rounds = _combine(
             spec.comb, keys, vals, owner, alive, alive, ns)
     post1 = jnp.zeros((ns,), jnp.int32).at[
         jnp.minimum(owner, ns - 1)].add(alive.astype(jnp.int32))
@@ -491,9 +554,10 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
         keys, vals, alive = keys[perm], vals[perm], alive[perm]
         owner = d[perm]
     if spec.comb is not None:
-        keys, vals, owner, alive = _combine(
+        keys, vals, owner, alive, r = _combine(
             spec.comb, keys, vals, owner, alive, alive, ns)
-    return keys, vals, owner, alive, gmoved_init, post1, p3moved
+        rounds = rounds + r
+    return keys, vals, owner, alive, gmoved_init, post1, p3moved, rounds
 
 
 # ---------------------------------------------------------------------------
@@ -989,27 +1053,29 @@ def _charge_two_level(ledger, topo, args, low, gmoved_init, post1, p3moved,
 def _dispatch(tracer, fn, spec: _PlanSpec, operands: tuple, ids: dict,
               rows: int, **attrs) -> tuple:
     """Run one replay program on host ``operands``; its outputs as host
-    arrays.  With tracing on, the stages are spans of their own, each
-    waited for: ``to_device`` (the inputs put on the device), ``jit_replay``
-    (dispatch until the outputs are ready on the device; ``compiled`` says
-    whether this program traced anew) and ``to_host`` (the outputs back).
-    With tracing off the program takes the host arrays and nothing waits
-    but the copy back."""
+    arrays, less the last (the fold rounds).  With tracing on, the stages
+    are spans of their own, each waited for: ``to_device`` (the inputs put
+    on the device), ``jit_replay`` (dispatch until the outputs are ready on
+    the device; ``compiled`` says whether this program traced anew,
+    ``fold_rounds`` how many rounds its COMB folds ran, per member of a
+    batch) and ``to_host`` (the outputs back).  With tracing off the
+    program takes the host arrays and nothing waits but the copy back."""
     import jax
 
     if not tracer.enabled:
         with jax.enable_x64(True):
             out = fn(spec, *operands)
-        return tuple(np.asarray(a) for a in out)
+        return tuple(np.asarray(a) for a in out[:-1])
     with jax.enable_x64(True):
         with tracer.span("to_device", **ids, **attrs):
             operands = jax.block_until_ready(jax.device_put(operands))
         with tracer.span("jit_replay", **ids, rows=rows, **attrs) as sp:
             traces = fn._cache_size()
             out = jax.block_until_ready(fn(spec, *operands))
-            sp.set(compiled=fn._cache_size() > traces)
+            sp.set(compiled=fn._cache_size() > traces,
+                   fold_rounds=np.asarray(out[-1]).tolist())
     with tracer.span("to_host", **ids, **attrs):
-        return tuple(np.asarray(a) for a in out)
+        return tuple(np.asarray(a) for a in out[:-1])
 
 
 def _charge_replay(ledger, topo, args: ShuffleArgs, low: JaxLowering,

@@ -18,7 +18,10 @@ What the conformance matrix (test_conformance.py) does not already pin:
 * the executor knob stack — per-call > per-tenant > cluster resolution;
 * plan-lifetime lowering reuse (``plancache.attach_lowering``);
 * the Pallas kernel plane (PART via ``partition_permute``, COMB via
-  ``segment_combine``) against the bit-exact default plane.
+  ``segment_combine``) against the bit-exact default plane;
+* the COMB fold in rounds by rank against the row-serial ``lax.scan`` fold
+  it replaced, byte for byte, alone and under ``vmap``, and the
+  ``fold_rounds`` it reports.
 """
 import math
 
@@ -29,10 +32,11 @@ from conformance import (assert_identical, conformance_case, copy_bufs,
                          make_bufs, make_topology, service_for, workers_for)
 from repro.core import (SUM, Msgs, PartFn, TeShuCluster, TeShuService,
                         datacenter)
-from repro.core.jaxplan import (kernel_global_stage, lower_plan, plan_decline,
-                                replay_cache_limit, replay_cache_size,
-                                set_kernel_plane, set_replay_cache_limit,
-                                trace_evictions, try_run_jax)
+from repro.core.jaxplan import (_combine, kernel_global_stage, lower_plan,
+                                plan_decline, replay_cache_limit,
+                                replay_cache_size, set_kernel_plane,
+                                set_replay_cache_limit, trace_evictions,
+                                try_run_jax)
 from repro.core.plancache import get_lowering
 
 WORKERS = list(range(8))
@@ -403,3 +407,182 @@ def test_try_run_jax_requires_a_plan():
                        part_fn=HASH_PART, comb_fn=SUM)
     bufs = make_bufs(WORKERS, "uniform")
     assert try_run_jax(sv.cluster, args, bufs) is None
+
+
+# ---------------------------------------------------------------------------
+# the COMB fold: rounds by rank within a segment vs the row-serial scan
+# ---------------------------------------------------------------------------
+
+def _scan_combine(comb, keys, vals, owner, alive, participate, sentinel):
+    """The oracle: the replay's COMB as it was before the round fold, a
+    ``lax.scan`` over every row (one row per step)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    folds = participate & alive
+    ckey = jnp.where(folds, keys, jnp.int64(0))
+    perm = jnp.argsort(ckey, stable=True)
+    so = jnp.where(alive, owner, sentinel)
+    perm = perm[jnp.argsort(so[perm], stable=True)]
+    keys, vals, owner, alive, folds = (
+        keys[perm], vals[perm], owner[perm], alive[perm], folds[perm])
+    prev_same = ((owner == jnp.roll(owner, 1))
+                 & (keys == jnp.roll(keys, 1))).at[0].set(False)
+    is_start = ~(prev_same & folds)
+    op = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[comb]
+
+    def fold(acc, x):
+        v, start = x
+        acc = jnp.where(start, v, op(acc, v))
+        return acc, acc
+
+    _, folded = lax.scan(fold, jnp.zeros_like(vals[0]), (vals, is_start))
+    seg_end = jnp.concatenate([is_start[1:], jnp.ones((1,), bool)])
+    return keys, folded, owner, alive & seg_end
+
+
+FOLD_ROWS = 6000
+FOLD_OWNERS = 4
+# one segment per length: each side of every fold phase's first round
+# (1, 8, 64, 512, 4096), the longest crossing them all
+PHASE_EDGES = (2, 8, 9, 64, 65, 512, 513, 4100)
+
+
+def _segment_lengths(shape, rng):
+    n = FOLD_ROWS
+    if shape == "singletons":
+        return np.ones(n, np.int64)
+    if shape == "short":
+        lens = rng.integers(1, 8, n)
+        return lens[:np.searchsorted(np.cumsum(lens), n) + 1]
+    if shape == "one":
+        return np.array([n])
+    if shape in ("pairs", "nines"):          # fill phase 1's / 2's window
+        size = 2 if shape == "pairs" else 9
+        return np.full(-(-n // size), size)
+    lens = list(PHASE_EDGES)                 # "zipf": Zipf(1.5) lengths
+    while sum(lens) < n:
+        lens.append(int(min(rng.zipf(1.5), 300)))
+    return np.array(lens)
+
+
+def _fold_case(comb, shape, participation, seed):
+    """Rows of segments of the given lengths (a unique key each, a random
+    owner), shuffled, with float64 payloads holding -0.0 (and NaN for MIN
+    and MAX), some rows already dead, and all owners folding or only owners
+    0 and 1 (a staged level's non-participating owners)."""
+    rng = np.random.default_rng(seed)
+    lens = _segment_lengths(shape, rng)
+    seg = np.repeat(np.arange(len(lens)), lens)[:FOLD_ROWS]
+    seg_owner = rng.integers(0, FOLD_OWNERS, len(lens))
+    perm = rng.permutation(FOLD_ROWS)
+    keys = (seg[perm] * 7 + 3).astype(np.int64)
+    owner = seg_owner[seg[perm]].astype(np.int32)
+    vals = rng.standard_normal((FOLD_ROWS, 3))
+    vals[rng.random(vals.shape) < 0.05] = -0.0
+    if comb != "sum":
+        vals[rng.random(vals.shape) < 0.02] = np.nan
+    alive = rng.random(FOLD_ROWS) > 0.03
+    participate = (np.ones(FOLD_ROWS, bool) if participation == "all"
+                   else owner < 2)
+    return keys, vals, owner, alive, participate
+
+
+def _longest(keys, owner, alive, participate):
+    """The longest (owner, key) segment among folding rows; 1 for none."""
+    f = alive & participate
+    pairs = np.stack([owner[f].astype(np.int64), keys[f]], axis=1)
+    if not len(pairs):
+        return 1
+    return int(np.unique(pairs, axis=0, return_counts=True)[1].max())
+
+
+def _assert_same_fold(got, want):
+    g_keys, g_vals, g_owner, g_alive = (np.asarray(a) for a in got)
+    w_keys, w_vals, w_owner, w_alive = (np.asarray(a) for a in want)
+    np.testing.assert_array_equal(g_keys, w_keys)
+    np.testing.assert_array_equal(g_owner, w_owner)
+    np.testing.assert_array_equal(g_alive, w_alive)
+    # every live row is a segment end: its fold, byte for byte
+    assert np.array_equal(g_vals[g_alive].view(np.uint64),
+                          w_vals[w_alive].view(np.uint64))
+
+
+def _jitted_folds(comb):
+    import jax
+
+    new = jax.jit(lambda *a: _combine(comb, *a, FOLD_OWNERS))
+    old = jax.jit(lambda *a: _scan_combine(comb, *a, FOLD_OWNERS))
+    return new, old
+
+
+@pytest.mark.parametrize("participation", ["all", "owners01"])
+@pytest.mark.parametrize("shape", ["singletons", "short", "pairs", "nines",
+                                   "one", "zipf"])
+@pytest.mark.parametrize("comb", ["sum", "min", "max"])
+def test_round_fold_matches_serial_scan(comb, shape, participation):
+    """Segment ends are byte-identical to the row-serial scan's (same IEEE
+    operations in the same order: -0.0 and NaN behave alike), and the fold
+    ran as many rounds as the longest segment."""
+    import jax
+
+    case = _fold_case(comb, shape, participation, seed=len(shape))
+    new, old = _jitted_folds(comb)
+    with jax.enable_x64(True):
+        *got, rounds = new(*case)
+        want = old(*case)
+    _assert_same_fold(got, want)
+    keys, _, owner, alive, participate = case
+    assert int(rounds) == _longest(keys, owner, alive, participate)
+
+
+@pytest.mark.parametrize("comb", ["sum", "min", "max"])
+def test_round_fold_under_vmap(comb):
+    """The batched program's fold (``prepare_batch``): two members whose
+    longest segments differ (at most 7 rows and about 4,100) fold in one
+    vmapped loop,
+    each byte-identical to its own serial scan, each with its own rounds."""
+    import jax
+
+    cases = [_fold_case(comb, "short", "all", seed=1),
+             _fold_case(comb, "zipf", "all", seed=2)]
+    stacked = [np.stack(parts) for parts in zip(*cases)]
+    new, old = _jitted_folds(comb)
+    with jax.enable_x64(True):
+        *got, rounds = jax.vmap(
+            lambda *a: _combine(comb, *a, FOLD_OWNERS))(*stacked)
+        for i, case in enumerate(cases):
+            _assert_same_fold([a[i] for a in got], old(*case))
+    assert np.asarray(rounds).tolist() == [
+        _longest(keys, owner, alive, part)
+        for keys, _, owner, alive, part in cases]
+    assert len(set(np.asarray(rounds).tolist())) == 2
+
+
+def test_jit_replay_reports_fold_rounds():
+    """``jit_replay`` carries ``fold_rounds``: a push replay's one global
+    COMB folds as many rounds as the most repeated key; a concat replay
+    folds none; a batched dispatch reports each member's."""
+    sv = _jax_service(tracing=True)
+    bufs = make_bufs(WORKERS, "zipf", n=283)
+    keys = np.concatenate([m.keys for m in bufs.values()])
+    most = int(np.unique(keys, return_counts=True)[1].max())
+    _run_twice(sv, "vanilla_push", bufs, WORKERS, comb_fn=SUM, shuffle_id=71)
+    _run_twice(sv, "vanilla_push", bufs, WORKERS, shuffle_id=72)
+    for sid, rounds in ((71, most), (72, 0)):
+        jit = [s for s in sv.spans(sid) if s["name"] == "jit_replay"]
+        assert [s["attrs"]["fold_rounds"] for s in jit] == [rounds]
+
+    cl = TeShuCluster(make_topology(), execution="auto", executor="jax",
+                      tracing=True)
+    tenants = [cl.tenant(f"t{i}") for i in range(2)]
+    for t in tenants:
+        _run_twice(t, "vanilla_push", bufs, WORKERS, comb_fn=SUM)
+    cl.obs.tracer.clear()
+    for t in tenants:
+        t.submit("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                 comb_fn=SUM)
+    cl.run_pending()
+    (jit,) = [s for s in cl.spans() if s["name"] == "jit_replay"]
+    assert jit["attrs"]["batch"] == 2
+    assert jit["attrs"]["fold_rounds"] == [most, most]
