@@ -840,25 +840,28 @@ def _attached_lowering(cluster, args) -> "JaxLowering | None":
 
 
 def try_run_jax(cluster: LocalCluster, args: ShuffleArgs,
-                bufs: dict[int, Msgs], manager=None) -> ShuffleResult | None:
+                bufs: dict[int, Msgs], manager=None,
+                batch_slot: "_BatchSlot | None" = None) -> ShuffleResult | None:
     """Replay ``args.plan`` as one jitted program; None = declined (the
-    service falls back to the vectorized executor)."""
+    service falls back to the vectorized executor).  ``batch_slot`` is this
+    submission's slice of a batched dispatch (:func:`prepare_batch`): the
+    replay consumes it in place of a dispatch of its own, unless the plan
+    has changed since the batch probe (then the slot stays unconsumed and
+    :func:`finish_batches` settles it)."""
     if not can_lower(cluster, args, bufs):
         return None
     low = _attached_lowering(cluster, args)
     if low is None:
         return None
-    slot = _BATCH_SLOTS.get(id(bufs))
-    if slot is not None and slot.plan is not args.plan:
-        slot = None                       # re-planned since the batch probe
-    if slot is not None:
-        _BATCH_SLOTS.pop(id(bufs), None)
+    if batch_slot is not None and batch_slot.plan is not args.plan:
+        batch_slot = None                 # re-planned since the batch probe
     tracer = cluster.obs.tracer
     if not tracer.enabled:
-        return _run_lowered(cluster, args, bufs, low, manager, batch_slot=slot)
+        return _run_lowered(cluster, args, bufs, low, manager, batch_slot)
     with tracer.span("exec", shuffle_id=args.shuffle_id, tenant=args.tenant,
-                     engine="jax", template=args.template_id):
-        return _run_lowered(cluster, args, bufs, low, manager, batch_slot=slot)
+                     engine="jax", template=args.template_id,
+                     batched=batch_slot is not None):
+        return _run_lowered(cluster, args, bufs, low, manager, batch_slot)
 
 
 # ---------------------------------------------------------------------------
@@ -871,37 +874,30 @@ class _BatchHandle:
     its slice or been abandoned (declined solo / invalidated mid-batch)."""
 
     def __init__(self, size: int):
-        self.size = size
         self.pending = size
         self.consumed = 0
-        self.closed = False
 
-    def member_done(self, ledger) -> None:
-        self.consumed += 1
-        self._settle(ledger)
-
-    def abandon(self, ledger) -> None:
-        self._settle(ledger)
-
-    def _settle(self, ledger) -> None:
+    def settle(self, ledger, consumed: bool) -> None:
+        self.consumed += consumed
         self.pending -= 1
-        if self.pending <= 0 and not self.closed:
-            self.closed = True
-            if self.consumed:
-                ledger.advance_epoch()
+        if self.pending == 0 and self.consumed:
+            ledger.advance_epoch()
 
 
 @dataclasses.dataclass
 class _BatchSlot:
+    """One member's slice of a batched dispatch, handed to its replay."""
+
     handle: _BatchHandle
     plan: object                     # the probed CompiledPlan (identity check)
     outputs: tuple                   # this member's slice of the stacked run
+    settled: bool = False
 
-
-# Pending batch slices, keyed by id() of the submission's buffer dict — the
-# one object that flows unchanged from admission through client.shuffle to
-# try_run_jax, so a member is matched without widening any call signature.
-_BATCH_SLOTS: dict[int, _BatchSlot] = {}
+    def settle(self, ledger, consumed: bool) -> None:
+        """Count this member out of the batch once, consumed or not."""
+        if not self.settled:
+            self.settled = True
+            self.handle.settle(ledger, consumed)
 
 
 def batch_signature(cluster: LocalCluster, args: ShuffleArgs,
@@ -927,49 +923,55 @@ def batch_signature(cluster: LocalCluster, args: ShuffleArgs,
             low.global_rank.tobytes(), low.bruck_flows, skew_sig)
 
 
-def prepare_batch(cluster: LocalCluster, members) -> "_BatchHandle | None":
+def prepare_batch(cluster: LocalCluster, members) -> "list[_BatchSlot] | None":
     """Run ONE stacked (vmapped) jit dispatch for ``members`` — a list of
-    ``(args, bufs)`` sharing :func:`batch_signature` — and register each
-    member's output slice for consumption by its own replay, which charges
-    its own tenant's ledger lanes exactly as a serial run would."""
+    ``(args, bufs)`` sharing :func:`batch_signature` — and return each
+    member's slice of it, in order, for its own replay, which charges its
+    own tenant's ledger lanes exactly as a serial run would.
+
+    With tracing on, the dispatch is a ``batch_dispatch`` span {members,
+    rows}: ``stage_batch`` (the host concatenation and stacking), then the
+    dispatch's ``to_device`` / ``jit_replay`` / ``to_host``."""
     if len(members) < 2:
         return None
     args0, bufs0 = members[0]
     low = get_lowering(args0.plan)
     if low is None or low is _DECLINED:
         return None
+    tracer = cluster.obs.tracer
     spec = _spec_of(args0)
     width = next((m.width for m in bufs0.values() if m.n), 1)
-    keys, vals, owner = [], [], []
-    for a, b in members:
-        per_w = [b.get(w, Msgs.empty(width)) for w in a.srcs]
-        keys.append(np.concatenate([m.keys for m in per_w]))
-        vals.append(np.concatenate([np.ascontiguousarray(m.vals)
-                                    for m in per_w]))
-        owner.append(np.concatenate([np.full(m.n, low.src_pos[w], np.int32)
-                                     for w, m in zip(a.srcs, per_w)]))
-    keys, vals, owner = np.stack(keys), np.stack(vals), np.stack(owner)
-    kind, shared = _program_inputs(spec, low)
-    sig = (spec, keys.shape[1:], vals.shape[1:],
-           tuple(a.shape for a in shared))
-    arrs = _dispatch(cluster.obs.tracer, _program(kind, sig, batch=len(members)),
-                     spec, (keys, vals, owner, *shared), {},
-                     rows=int(keys.size), batch=len(members))
+    with tracer.span("batch_dispatch", members=len(members)) as sp:
+        with tracer.span("stage_batch"):
+            keys, vals, owner = [], [], []
+            for a, b in members:
+                per_w = [b.get(w, Msgs.empty(width)) for w in a.srcs]
+                keys.append(np.concatenate([m.keys for m in per_w]))
+                vals.append(np.concatenate([np.ascontiguousarray(m.vals)
+                                            for m in per_w]))
+                owner.append(np.concatenate(
+                    [np.full(m.n, low.src_pos[w], np.int32)
+                     for w, m in zip(a.srcs, per_w)]))
+            keys, vals, owner = np.stack(keys), np.stack(vals), np.stack(owner)
+        sp.set(rows=int(keys.size))
+        kind, shared = _program_inputs(spec, low)
+        sig = (spec, keys.shape[1:], vals.shape[1:],
+               tuple(a.shape for a in shared))
+        arrs = _dispatch(tracer, _program(kind, sig, batch=len(members)),
+                         spec, (keys, vals, owner, *shared), {},
+                         rows=int(keys.size), batch=len(members))
     handle = _BatchHandle(len(members))
-    for i, (a, b) in enumerate(members):
-        _BATCH_SLOTS[id(b)] = _BatchSlot(
-            handle=handle, plan=a.plan,
-            outputs=tuple(x[i] for x in arrs))
-    return handle
+    return [_BatchSlot(handle=handle, plan=a.plan,
+                       outputs=tuple(x[i] for x in arrs))
+            for i, (a, _b) in enumerate(members)]
 
 
-def finish_batches(handles, ledger) -> None:
-    """Abandon any slice left unconsumed (its member declined solo or was
-    re-planned mid-batch) so the shared epoch barrier still closes."""
-    live = {id(h) for h in handles}
-    stale = [k for k, slot in _BATCH_SLOTS.items() if id(slot.handle) in live]
-    for k in stale:
-        _BATCH_SLOTS.pop(k).handle.abandon(ledger)
+def finish_batches(slots, ledger) -> None:
+    """Abandon every slot of a pass that no replay consumed (its member
+    declined solo or was re-planned mid-batch), so each batch's shared
+    epoch barrier still closes."""
+    for slot in slots:
+        slot.settle(ledger, consumed=False)
 
 
 # ---------------------------------------------------------------------------
@@ -1250,7 +1252,7 @@ def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
     if batch_slot is None:
         ledger.advance_epoch()            # shuffle completion is a barrier
     else:
-        batch_slot.handle.member_done(ledger)   # the batch settles as one
+        batch_slot.settle(ledger, consumed=True)   # the batch settles as one
     after = ledger.snapshot()
     if manager is not None:
         for w in participants:

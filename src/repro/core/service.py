@@ -733,42 +733,18 @@ class TeShuCluster:
         by_coflow: dict[tuple[str, str], list] = {}
         for s in subs:
             by_coflow.setdefault(s.coflow_id, []).append(s)
-        batch_handles, batches = self._prepare_batches(subs)
-        t0 = self.cluster.ledger.modelled_time()
-        results: dict[int, ShuffleResult] = {}
-        failures: dict[int, str] = {}
-        ccts: dict[tuple[str, str], float] = {}
         tracer = self.obs.tracer
-        for i, e in enumerate(entries):
-            if el is not None and i > 0:
-                # mid-batch boundary: the policy may grow the cluster between
-                # coflows; later coflows are re-targeted onto burst workers
-                remaining = [s for e2 in entries[i:]
-                             for s in by_coflow.get(e2.coflow_id, ())]
-                self._elastic_boundary(i, len(entries) - i, remaining)
-            for s in by_coflow.get(e.coflow_id, ()):
-                client = self._clients[s.tenant]
-                wait = max(0.0, time.monotonic() - s.ts) if s.ts else 0.0
-                self._m_admission_wait.observe(wait, tenant=s.tenant)
-                if tracer.enabled:
-                    tracer.point("admission_pass", tenant=s.tenant,
-                                 ticket=s.ticket, stage=s.stage, wait_s=wait)
-                try:
-                    results[s.ticket] = client.shuffle(
-                        s.template_id, s.bufs, s.srcs, s.dsts, **s.kwargs)
-                except Exception as exc:  # noqa: BLE001 — isolation: one
-                    # tenant's failing shuffle must not destroy the rest of
-                    # the drained batch; the caller gets the exception back
-                    results[s.ticket] = exc
-                    failures[s.ticket] = f"{type(exc).__name__}: {exc}"
-            ccts[e.coflow_id] = self.cluster.ledger.modelled_time() - t0
-        if batch_handles:
-            # close out any stacked slice whose member ended up declining
-            # solo (re-planned / invalidated mid-pass) so the shared epoch
-            # barrier still settles
-            jx = sys.modules.get("repro.core.jaxplan")
-            if jx is not None:
-                jx.finish_batches(batch_handles, self.cluster.ledger)
+        with tracer.span("run_pending", submissions=len(subs)) as pass_span:
+            slots, batches = self._prepare_batches(subs)
+            pass_span.set(batches=len(batches))
+            ccts, failures, results = self._run_scheduled(
+                entries, by_coflow, slots)
+        if slots:
+            # settle any stacked slice whose member ended up declining solo
+            # (re-planned / invalidated mid-pass) so the shared epoch
+            # barrier still closes
+            from .jaxplan import finish_batches
+            finish_batches(slots.values(), self.cluster.ledger)
         if el is not None:
             # close the pass with a realized-CCT sample, then the pass-end
             # idle point (TTL expiry + policy scale-in hysteresis tick)
@@ -791,6 +767,42 @@ class TeShuCluster:
         if el is not None:
             self._last_schedule["scale_events"] = el.events[n_events0:]
         return results
+
+    def _run_scheduled(self, entries, by_coflow, slots):
+        """Execute a pass's submissions in scheduled order, each member of a
+        batch with its slice; per-coflow completion times, failures and a
+        result per ticket."""
+        el = self._elastic
+        t0 = self.cluster.ledger.modelled_time()
+        results: dict[int, ShuffleResult | Exception] = {}
+        failures: dict[int, str] = {}
+        ccts: dict[tuple[str, str], float] = {}
+        tracer = self.obs.tracer
+        for i, e in enumerate(entries):
+            if el is not None and i > 0:
+                # mid-batch boundary: the policy may grow the cluster between
+                # coflows; later coflows are re-targeted onto burst workers
+                remaining = [s for e2 in entries[i:]
+                             for s in by_coflow.get(e2.coflow_id, ())]
+                self._elastic_boundary(i, len(entries) - i, remaining)
+            for s in by_coflow.get(e.coflow_id, ()):
+                client = self._clients[s.tenant]
+                wait = max(0.0, time.monotonic() - s.ts) if s.ts else 0.0
+                self._m_admission_wait.observe(wait, tenant=s.tenant)
+                if tracer.enabled:
+                    tracer.point("admission_pass", tenant=s.tenant,
+                                 ticket=s.ticket, stage=s.stage, wait_s=wait)
+                try:
+                    results[s.ticket] = self._shuffle(
+                        client, s.template_id, s.bufs, s.srcs, s.dsts,
+                        batch_slot=slots.get(s.ticket), **s.kwargs)
+                except Exception as exc:  # noqa: BLE001 — isolation: one
+                    # tenant's failing shuffle must not destroy the rest of
+                    # the drained batch; the caller gets the exception back
+                    results[s.ticket] = exc
+                    failures[s.ticket] = f"{type(exc).__name__}: {exc}"
+            ccts[e.coflow_id] = self.cluster.ledger.modelled_time() - t0
+        return ccts, failures, results
 
     # ---- elastic hooks ---------------------------------------------------------
     def _elastic_boundary(self, executed: int, pending: int,
@@ -849,17 +861,49 @@ class TeShuCluster:
             return True
         return self.plan_cache.has_repair_relatives(key, tenant)
 
-    def _prepare_batches(self, subs) -> tuple[list, list[dict]]:
+    def _prepare_batches(self, subs) -> tuple[dict, list[dict]]:
         """Group drained submissions that will replay on the jax executor
         with one trace signature AND identical routing tables, and run each
         group of >= 2 as ONE vmapped dispatch up front
-        (:func:`repro.core.jaxplan.prepare_batch`).  Members then consume
-        their output slice when the scheduled pass reaches them, charging
-        their own tenant's ledger lanes exactly as a serial replay would;
-        the probe itself is side-effect-free (``plan_cache.peek``, no
-        counters), so per-member metrics/journal records are written only by
-        the real execution path.  A submission that fails the probe simply
-        runs solo and reports its own fallback reason."""
+        (:func:`repro.core.jaxplan.prepare_batch`).  Returns each member's
+        output slice by ticket, which its replay consumes when the scheduled
+        pass reaches it, charging its own tenant's ledger lanes exactly as a
+        serial replay would, and a summary per batch.  The probe itself is
+        side-effect-free (``plan_cache.peek``, no counters), so per-member
+        metrics/journal records are written only by the real execution path.
+        A submission that fails the probe simply runs solo and reports its
+        own fallback reason.  With tracing on, the grouping is a
+        ``batch_probe`` span {candidates, grouped}."""
+        with self.obs.tracer.span("batch_probe") as probe_span:
+            n_candidates, groups = self._batch_groups(subs)
+            probe_span.set(candidates=n_candidates,
+                           grouped=sum(len(m) for m in groups))
+        slots: dict[int, object] = {}
+        batches = []
+        if not groups:
+            return slots, batches
+        from . import jaxplan
+        for members in groups:
+            member_slots = jaxplan.prepare_batch(
+                self.cluster, [(p, s.bufs) for p, s in members])
+            if member_slots is None:
+                continue
+            slots.update((s.ticket, slot)
+                         for (_, s), slot in zip(members, member_slots))
+            batches.append({
+                "template": members[0][0].template_id,
+                "size": len(members),
+                "tickets": [s.ticket for _, s in members],
+                "tenants": sorted({s.tenant for _, s in members}),
+            })
+            self._m_batched.inc(template=members[0][0].template_id)
+        return slots, batches
+
+    def _batch_groups(self, subs) -> tuple[int, list[list]]:
+        """How many drained submissions would replay a cached plan on the
+        jax executor, and the groups of >= 2 of them that share a
+        :func:`repro.core.jaxplan.batch_signature`, each member as
+        ``(probe args, submission)``."""
         candidates = []
         for s in subs:
             client = self._clients.get(s.tenant)
@@ -906,30 +950,14 @@ class TeShuCluster:
                 skew_threshold=skew_threshold, plan=plan)
             candidates.append((probe, s))
         if len(candidates) < 2:
-            return [], []
+            return len(candidates), []
         from . import jaxplan
         groups: dict[tuple, list] = {}
         for probe, s in candidates:
             sig = jaxplan.batch_signature(self.cluster, probe, s.bufs)
             if sig is not None:
                 groups.setdefault(sig, []).append((probe, s))
-        handles, batches = [], []
-        for members in groups.values():
-            if len(members) < 2:
-                continue
-            handle = jaxplan.prepare_batch(
-                self.cluster, [(p, s.bufs) for p, s in members])
-            if handle is None:
-                continue
-            handles.append(handle)
-            batches.append({
-                "template": members[0][0].template_id,
-                "size": len(members),
-                "tickets": [s.ticket for _, s in members],
-                "tenants": sorted({s.tenant for _, s in members}),
-            })
-            self._m_batched.inc(template=members[0][0].template_id)
-        return handles, batches
+        return len(candidates), [m for m in groups.values() if len(m) >= 2]
 
     def last_schedule(self) -> dict | None:
         """The most recent ``run_pending`` pass: policy, effective weights,
@@ -939,16 +967,22 @@ class TeShuCluster:
     # ---- the shuffle path ------------------------------------------------------
     def _shuffle(self, client: TenantClient, template_id: str,
                  bufs: dict[int, Msgs], srcs: Sequence[int],
-                 dsts: Sequence[int], *, part_fn: PartFn,
-                 comb_fn: Combiner | None, rate: float,
-                 shuffle_id: int | None, seed: int,
-                 execution: str | None, resilience: str | None,
-                 balance: str | None, skew_threshold: float | None,
-                 streaming: str | None, chunk_bytes: int | None,
-                 max_inflight: int | None,
+                 dsts: Sequence[int], *, part_fn: PartFn = HASH_PART,
+                 comb_fn: Combiner | None = None, rate: float = 0.01,
+                 shuffle_id: int | None = None, seed: int = 0,
+                 execution: str | None = None,
+                 resilience: str | None = None,
+                 balance: str | None = None,
+                 skew_threshold: float | None = None,
+                 streaming: str | None = None,
+                 chunk_bytes: int | None = None,
+                 max_inflight: int | None = None,
                  max_retries: int | None = None,
                  executor: str | None = None,
-                 storage: str | None = None) -> ShuffleResult:
+                 storage: str | None = None,
+                 batch_slot=None) -> ShuffleResult:
+        """``TenantClient.shuffle``; ``batch_slot`` is the submission's
+        slice of a batched dispatch when ``run_pending`` replays it."""
         tenant = client.tenant_id
         execution = _check_mode("execution", client.knob("execution", execution),
                                 EXECUTION_MODES)
@@ -1066,7 +1100,7 @@ class TeShuCluster:
                 try:
                     if resilience == "off":
                         res = self._run_plain(args, bufs, key, execution,
-                                              executor, repaired)
+                                              executor, repaired, batch_slot)
                     else:
                         res = self._run_resilient(
                             args, bufs, key, execution, resilience, repaired,
@@ -1127,7 +1161,8 @@ class TeShuCluster:
 
     # ---- execution paths ------------------------------------------------------
     def _execute(self, args: ShuffleArgs, bufs: dict[int, Msgs],
-                 execution: str, executor: str = "vectorized") -> ShuffleResult:
+                 execution: str, executor: str = "vectorized",
+                 batch_slot=None) -> ShuffleResult:
         fallbacks: list[dict] = []
         res = None
         if args.plan is not None and execution == "auto":
@@ -1138,7 +1173,7 @@ class TeShuCluster:
                 # rung's decline reason is kept for explain()/metrics
                 from .jaxplan import decline_reason, try_run_jax
                 res = try_run_jax(self.cluster, args, bufs,
-                                  manager=self.manager)
+                                  manager=self.manager, batch_slot=batch_slot)
                 if res is None:
                     fallbacks.append({
                         "engine": "jax",
@@ -1194,12 +1229,12 @@ class TeShuCluster:
 
     def _run_plain(self, args: ShuffleArgs, bufs: dict[int, Msgs], key: tuple,
                    execution: str, executor: str = "vectorized",
-                   repaired: bool = False) -> ShuffleResult:
+                   repaired: bool = False, batch_slot=None) -> ShuffleResult:
         if args.plan is None:
             res = run_shuffle(self.cluster, args, bufs, manager=self.manager)
             self._compile(args, key, res)
             return res
-        res = self._execute(args, bufs, execution, executor)
+        res = self._execute(args, bufs, execution, executor, batch_slot)
         res.repaired = repaired
         # Drift check: measured reductions from this cached run vs the plan's
         # baseline; a drifted entry is dropped so the next call re-instantiates.

@@ -15,6 +15,9 @@ What the conformance matrix (test_conformance.py) does not already pin:
   oldest programs and counts ``trace_evictions``;
 * batched multi-tenant dispatch — same-signature wfair submissions execute
   as one vmapped program with per-tenant ledger lanes identical to serial;
+  four tenants' streams of submit + ``run_pending`` passes deliver each
+  member's own numpy group-by bit for bit (sum, min, max; members whose
+  longest segments differ a thousandfold), each member handed its slice;
 * the executor knob stack — per-call > per-tenant > cluster resolution;
 * plan-lifetime lowering reuse (``plancache.attach_lowering``);
 * the Pallas kernel plane (PART via ``partition_permute``, COMB via
@@ -30,8 +33,8 @@ import pytest
 
 from conformance import (assert_identical, conformance_case, copy_bufs,
                          make_bufs, make_topology, service_for, workers_for)
-from repro.core import (SUM, Msgs, PartFn, TeShuCluster, TeShuService,
-                        datacenter)
+from repro.core import (COMBINERS, SUM, Msgs, PartFn, TeShuCluster,
+                        TeShuService, datacenter)
 from repro.core.jaxplan import (_combine, kernel_global_stage, lower_plan,
                                 plan_decline, replay_cache_limit,
                                 replay_cache_size, set_kernel_plane,
@@ -381,6 +384,170 @@ def test_batch_member_declines_with_its_own_reason():
     odd = results[odd_ticket]
     assert odd.engine == "vectorized" and not odd.batched
     assert odd.fallback_reason == "unsupported_part_fn"
+
+
+STREAM_ROWS = 8_000
+
+
+def _lineitem(seed: int, hot: int = 0, rows: int = STREAM_ROWS):
+    """A small seeded lineitem-like table: dbgen's sparse order keys with
+    1-7 lines per order (``hot`` of the rows on order 1, generated first;
+    ``hot=-1``: every order one line), and quantity, price, discount and tax
+    as float64 hundredths, so every sum is an exact integer."""
+    rng = np.random.default_rng(seed)
+    if hot < 0:
+        orders = np.arange(1, rows + 1, dtype=np.int64)
+    else:
+        orders = np.concatenate([
+            np.ones(hot, np.int64),
+            np.repeat(np.arange(2, rows + 2, dtype=np.int64),
+                      rng.integers(1, 8, rows))[:rows - hot]])
+    keys = ((orders >> 3) << 5) | (orders & 7)
+    vals = np.stack([rng.integers(100, 5_001, rows),
+                     rng.integers(90_000, 10_500_001, rows),
+                     rng.integers(0, 11, rows), rng.integers(0, 9, rows)],
+                    axis=1).astype(np.float64)
+    return keys, vals
+
+
+def _table_bufs(table):
+    """The table spread evenly over the workers in row order."""
+    keys, vals = table
+    parts = np.array_split(np.arange(keys.size), len(WORKERS))
+    return {w: Msgs(keys[p], vals[p]) for w, p in zip(WORKERS, parts)}
+
+
+def _numpy_group_by(table, comb: str):
+    keys, vals = table
+    uniq, inv = np.unique(keys, return_inverse=True)
+    ufunc, start = {"sum": (np.add, 0.0), "min": (np.minimum, np.inf),
+                    "max": (np.maximum, -np.inf)}[comb]
+    out = np.full((uniq.size, vals.shape[1]), start)
+    ufunc.at(out, inv, vals)
+    return uniq, out
+
+
+def _delivered(res):
+    """Every destination's rows, sorted by key; a key on two destinations
+    shows as a repeated key."""
+    keys = np.concatenate([m.keys for m in res.bufs.values()])
+    vals = np.concatenate([m.vals for m in res.bufs.values()])
+    order = np.argsort(keys, kind="stable")
+    return keys[order], vals[order]
+
+
+def _stream_passes(template, comb, tables, passes, tracing, rotate=True):
+    """Four tenants (``s0``..``s3``) each submit a shuffle of a table, then
+    one ``run_pending()`` per pass: in pass ``r`` tenant ``t`` takes table
+    ``(r + t) % 4`` (``rotate``) or always table ``t``.  Returns the cluster
+    and, per pass, each tenant's (table index, result)."""
+    cl = TeShuCluster(make_topology(), execution="auto", executor="jax",
+                      tracing=tracing)
+    tenants = [cl.tenant(f"s{t}") for t in range(4)]
+    out = []
+    for r in range(passes):
+        idx = [(r + t) % 4 if rotate else t for t in range(4)]
+        tickets = [c.submit(template, _table_bufs(tables[i]), WORKERS,
+                            WORKERS, comb_fn=COMBINERS[comb])
+                   for c, i in zip(tenants, idx)]
+        got = cl.run_pending()
+        out.append([(i, got[tk]) for i, tk in zip(idx, tickets)])
+    return cl, out
+
+
+@pytest.mark.parametrize("template", ["vanilla_push", "network_aware"])
+@pytest.mark.parametrize("comb", ["sum", "min", "max"])
+def test_four_streams_batched_match_numpy_group_by(template, comb):
+    """TPC-H's throughput test in small: four tenants submit a group-by of
+    their own table each pass, one ``run_pending()`` runs the pass as ONE
+    vmapped dispatch, and every member delivers exactly the numpy group-by
+    of its own table, bit for bit, with tracing on and off alike."""
+    tables = [_lineitem(seed) for seed in range(4)]
+    refs = [_numpy_group_by(t, comb) for t in tables]
+    delivered = {}
+    for tracing in (False, True):
+        cl, passes = _stream_passes(template, comb, tables, 5, tracing)
+        batches = cl.obs.metrics.get("teshu_batched_dispatches_total",
+                                     template=template)
+        assert batches == 4                 # pass 0 instantiates the plans
+        for r, members in enumerate(passes[1:], start=1):
+            for i, res in members:
+                assert res.engine == "jax" and res.batched
+                assert res.fallback_reason is None
+                keys, vals = _delivered(res)
+                assert np.array_equal(keys, refs[i][0])
+                assert np.array_equal(vals.view(np.int64),
+                                      refs[i][1].view(np.int64))
+                delivered.setdefault(tracing, []).append((keys, vals))
+        if tracing:
+            jit = [s for s in cl.spans() if s["name"] == "jit_replay"]
+            assert [s["attrs"]["batch"] for s in jit] == [4] * 4
+    for (k_off, v_off), (k_on, v_on) in zip(delivered[False],
+                                            delivered[True]):
+        assert np.array_equal(k_off, k_on)
+        assert np.array_equal(v_off.view(np.int64), v_on.view(np.int64))
+
+
+@pytest.mark.parametrize("template", ["vanilla_push", "network_aware"])
+@pytest.mark.parametrize("comb", ["sum", "max"])
+def test_batched_members_whose_longest_segments_differ_1000x(template, comb):
+    """One vmapped fold serves members whose longest equal-key segments
+    differ a thousandfold (every order one line, against 2,000 lines on one
+    order): the loop runs as long as the longest member needs, and each
+    member still delivers its own numpy group-by exactly."""
+    tables = [_lineitem(0, hot=-1), _lineitem(1, hot=-1),
+              _lineitem(2, hot=2_000), _lineitem(3)]
+    longest = []
+    for keys, _ in tables:
+        per_worker = [np.unique(b.keys, return_counts=True)[1].max()
+                      for b in _table_bufs((keys, keys)).values()]
+        longest.append(int(max(per_worker)))
+    assert max(longest) >= 1_000 * min(longest)
+    cl, passes = _stream_passes(template, comb, tables, 3, True,
+                                rotate=False)
+    for members in passes[1:]:
+        for i, res in members:
+            assert res.batched
+            keys, vals = _delivered(res)
+            ref = _numpy_group_by(tables[i], comb)
+            assert np.array_equal(keys, ref[0])
+            assert np.array_equal(vals.view(np.int64), ref[1].view(np.int64))
+    rounds = [s["attrs"]["fold_rounds"] for s in cl.spans()
+              if s["name"] == "jit_replay"][-1]
+    assert max(rounds) >= 250 * min(rounds)
+    if template == "vanilla_push":      # one COMB: its rounds are the longest
+        assert rounds[2] >= 2_000 and rounds[0] == 1
+
+
+def test_members_sharing_one_bufs_dict_get_their_own_slices():
+    """Each member's replay is handed its own slice by ``run_pending``, not
+    found through its buffers: two submissions of one tenant that share one
+    ``bufs`` dict (the tables differ only by a shift of the payloads) each
+    deliver their own group-by."""
+    cl = TeShuCluster(make_topology(), execution="auto", executor="jax")
+    a, b = cl.tenant("a"), cl.tenant("b")
+    table = _lineitem(5)
+    shared = _table_bufs(table)
+    for _ in range(2):                              # plans, then the program
+        for t in (a, b):
+            t.submit("vanilla_push", shared, WORKERS, WORKERS, comb_fn=SUM)
+        cl.run_pending()
+    other = _table_bufs((table[0], table[1] + 100.0))
+    tickets = [a.submit("vanilla_push", shared, WORKERS, WORKERS,
+                        comb_fn=SUM),
+               b.submit("vanilla_push", other, WORKERS, WORKERS,
+                        comb_fn=SUM),
+               b.submit("vanilla_push", shared, WORKERS, WORKERS,
+                        comb_fn=SUM)]
+    got = cl.run_pending()
+    (entry,) = cl.last_schedule()["batches"]
+    assert entry["size"] == 3
+    for tk, vals in zip(tickets, (table[1], table[1] + 100.0, table[1])):
+        assert got[tk].batched
+        keys, out = _delivered(got[tk])
+        ref = _numpy_group_by((table[0], vals), "sum")
+        assert np.array_equal(keys, ref[0])
+        assert np.array_equal(out.view(np.int64), ref[1].view(np.int64))
 
 
 # ---------------------------------------------------------------------------

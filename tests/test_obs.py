@@ -14,6 +14,8 @@ What the rest of the suite does not already pin:
   invalidations are machine-checkable strings — and the rungs retired by
   the full-coverage lowering (``template_not_lowerable`` on built-ins,
   ``skew_rebalance_triggered``) are asserted dead;
+* a ``run_pending`` pass's span tree (batch probe, batched dispatch, the
+  members' shuffles) and its silence with tracing off;
 * the doctor CLI (``python -m repro.launch.doctor``) over a real journal;
 * the Shuffle Manager's progress/durations/stragglers views (satellite 3)
   and the versioned journal schema with tolerant migration (satellite 6).
@@ -548,6 +550,94 @@ def test_batched_dispatch_records_its_device_stages():
     assert device[1]["attrs"]["rows"] == 3 * 8 * 349
     assert [s["name"] for s in spans if s["name"] == "exec"] == ["exec"] * 3
     assert sum(s["name"] == "ledger_replay" for s in spans) == 3
+
+
+def _four_stream_pass(tracing):
+    """Four tenants with their plans and the batched program warm, then one
+    traced-or-not ``run_pending`` pass of one submission each."""
+    cl = TeShuCluster(make_topology(), execution="auto", executor="jax",
+                      tracing=tracing)
+    tenants = [cl.tenant(f"s{i}") for i in range(4)]
+    bufs = make_bufs(WORKERS, "uniform", n=359)
+    for _ in range(2):                      # plans, then the batched program
+        for t in tenants:
+            t.submit("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                     comb_fn=SUM)
+        cl.run_pending()
+    cl.obs.tracer.clear()
+    tickets = [t.submit("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                        comb_fn=SUM) for t in tenants]
+    results = cl.run_pending()
+    assert all(results[tk].batched for tk in tickets)
+    return cl
+
+
+def test_run_pending_pass_is_a_span_tree():
+    """A pass is rooted at ``run_pending``: the batch probe, the one batched
+    dispatch (its host staging and device stages) and the members' shuffles
+    nest under it, and each member's ``exec`` says it replayed a slice."""
+    cl = _four_stream_pass(tracing=True)
+    spans = cl.spans()
+    (root,) = [s for s in spans if s["name"] == "run_pending"]
+    assert root["parent_id"] is None
+    assert root["attrs"] == {"submissions": 4, "batches": 1}
+    probe, dispatch = sorted(
+        (s for s in spans if s["name"] in ("batch_probe", "batch_dispatch")),
+        key=lambda s: s["t0"])
+    assert probe["name"] == "batch_probe" and dispatch["name"] == "batch_dispatch"
+    assert probe["parent_id"] == dispatch["parent_id"] == root["span_id"]
+    assert probe["attrs"] == {"candidates": 4, "grouped": 4}
+    assert dispatch["attrs"] == {"members": 4, "rows": 4 * 8 * 359}
+    assert probe["t1"] <= dispatch["t0"]
+    stages = sorted(_children(spans, dispatch), key=lambda s: s["t0"])
+    assert [s["name"] for s in stages] == ["stage_batch", "to_device",
+                                           "jit_replay", "to_host"]
+    assert all(_within(s, dispatch) for s in stages)
+    assert stages[2]["attrs"]["batch"] == 4
+    shuffles = [s for s in spans if s["name"] == "shuffle"]
+    assert len(shuffles) == 4
+    assert all(s["parent_id"] == root["span_id"] and _within(s, root)
+               and s["t0"] >= dispatch["t1"] for s in shuffles)
+    execs = [s for s in spans if s["name"] == "exec"]
+    assert [s["attrs"]["batched"] for s in execs] == [True] * 4
+    for exe in execs:                   # no device stage inside a member
+        assert {s["name"] for s in _children(spans, exe)} == {
+            "stage_inputs", "ledger_replay", "split_outputs"}
+    # a replay of its own says so
+    t = cl.tenant("s0")
+    t.shuffle("vanilla_push", make_bufs(WORKERS, "uniform", n=359), WORKERS,
+              WORKERS, comb_fn=SUM, shuffle_id=917)
+    (solo,) = [s for s in cl.spans(917) if s["name"] == "exec"]
+    assert solo["attrs"]["batched"] is False
+
+
+def test_tracing_off_pass_builds_no_annotation_and_adds_no_transfer(
+        monkeypatch):
+    """With tracing off a batched pass opens no span, builds no profiler
+    annotation, and puts or waits on nothing beyond the program's call."""
+    import jax
+
+    counts = {"annotation": 0, "device_put": 0, "block_until_ready": 0}
+
+    class CountedAnnotation(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            counts["annotation"] += 1
+            super().__init__(*a, **kw)
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            counts[name] += 1
+            return fn(*a, **kw)
+        return wrapper
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountedAnnotation)
+    monkeypatch.setattr(jax, "device_put", counted("device_put",
+                                                   jax.device_put))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        counted("block_until_ready", jax.block_until_ready))
+    cl = _four_stream_pass(tracing=False)
+    assert cl.spans() == []
+    assert counts == {"annotation": 0, "device_put": 0,
+                      "block_until_ready": 0}
 
 
 def _profiled(tmp_path, fn):
