@@ -1,6 +1,7 @@
 """Run the served shuffle path once on one TPU chip and check what it returns.
 
     python chip_smoke.py               # TPC-H lineitem aggregation shuffle, one chip
+    python chip_smoke.py --wire        # only the replay's float64 wire format
     python chip_smoke.py --four-chip   # expert-parallel MoE dispatch, 4 chips vs 1
 
 The default phase drives ``TeShuCluster`` -> ``tenant()`` -> ``shuffle()`` /
@@ -8,7 +9,10 @@ The default phase drives ``TeShuCluster`` -> ``tenant()`` -> ``shuffle()`` /
 with a ``lineitem`` table generated from ``--seed`` with dbgen's
 distributions, keyed by ``l_orderkey`` and summing the four numeric columns.
 Every output is checked against a plain numpy group-by written here, apart
-from ``repro.core``.  ``--four-chip`` runs only one MoE FFN layer of
+from ``repro.core``.  Then the replay's wire format for float64 (the words
+its keys and payloads cross in) is held to the runtime's own transfers bit
+for bit, on general doubles and edge patterns and on whole replays of
+non-integer payloads; ``--wire`` runs that phase alone.  ``--four-chip`` runs only one MoE FFN layer of
 qwen3-moe-235b-a22b expert-parallel over a 2x2 mesh and the same layer on one
 chip.
 
@@ -240,7 +244,177 @@ def _log_device_memory(devices) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Phase 2 (--four-chip): expert-parallel MoE dispatch over a 2x2 mesh
+# Phase 2: the replay's wire format against the runtime's own transfers
+# ---------------------------------------------------------------------------
+
+WIRE_VALUES = 200_000
+
+# float64 bit patterns at the edges: NaNs with payload bits (signalling and
+# quiet, both signs), both zeros, both infinities, the least and greatest
+# double subnormals and normals, float32's least subnormal and greatest
+# finite as doubles, and a double just above float32's range
+WIRE_EDGE_BITS = (0x7FF0000000000001, 0xFFF8000000000123, 0x7FF4000000000000,
+                  0x0, 0x8000000000000000, 0x7FF0000000000000,
+                  0xFFF0000000000000, 0x1, 0x800FFFFFFFFFFFFF,
+                  0x0010000000000000, 0x7FEFFFFFFFFFFFFF, 0x36A0000000000000,
+                  0x47EFFFFFE0000000, 0x47F0000000000000)
+
+
+def wire_values(seed: int) -> dict:
+    """Float64 test values by kind: random bits whose float32 pair lies in
+    float32's normal range, random bits whose pair's low part falls below
+    it, decimal hundredths (``12345.67``), doubles below float32's range
+    (double subnormals among them) and above it, and the edge patterns."""
+    rng = np.random.default_rng(seed)
+    n = WIRE_VALUES
+
+    def doubles(exp_lo, exp_hi):
+        sign = rng.integers(0, 2, n, dtype=np.uint64) << np.uint64(63)
+        exp = rng.integers(exp_lo, exp_hi, n, dtype=np.uint64) << np.uint64(52)
+        frac = rng.integers(0, 2**52, n, dtype=np.uint64)
+        return (sign | exp | frac).view(np.float64)
+    return {
+        "general": doubles(1023 - 101, 1023 + 128),
+        "low_part_below_float32": doubles(1023 - 126, 1023 - 101),
+        "hundredths": rng.integers(-10**13, 10**13, n) / 100,
+        "below_float32": np.concatenate([doubles(0, 1),
+                                         doubles(1, 1023 - 126)]),
+        "above_float32": doubles(1023 + 128, 2047),
+        "edge": np.array(WIRE_EDGE_BITS, np.uint64).view(np.float64),
+    }
+
+
+def _held_by_float32_arithmetic(x: np.ndarray):
+    """What a TPU's float32 arithmetic keeps of each finite double ``x``:
+    its float32 pair (high the nearest float32, low the nearest to the
+    rest) with each part below float32's normal range flushed to zero; and
+    where that flush changes the value (a nonzero ``x`` whose high part is
+    zero or subnormal, or whose low part is subnormal)."""
+    tiny = np.finfo(np.float32).tiny
+    with np.errstate(over="ignore", invalid="ignore"):
+        hi = x.astype(np.float32)
+        lo = np.where(np.isfinite(hi), (x - hi).astype(np.float32), 0)
+    sub_hi = np.isfinite(x) & (x != 0) & (np.abs(hi) < tiny)
+    sub_lo = (lo != 0) & (np.abs(lo) < tiny)
+    held = (np.where(np.abs(hi) < tiny, 0, hi).astype(np.float64)
+            + np.where(np.abs(lo) < tiny, 0, lo))
+    return held, sub_hi | sub_lo
+
+
+def _wire_faults(x: np.ndarray, got: np.ndarray, runtime: np.ndarray,
+                 pairs: bool) -> dict:
+    """Values of ``got`` that break the wire's contract, and the first few
+    as hex bits of (value, runtime's, got): each must carry the runtime's
+    bits, save, where float64 crosses as float32 ``pairs``, a value whose
+    pair has a part below float32's normal range, which may instead come
+    back as float32 arithmetic holds it (the wire forms and splits the pair
+    on the device; the runtime moves its parts as data)."""
+    held, flushed = _held_by_float32_arithmetic(x)
+    differ = got.view(np.uint64) != runtime.view(np.uint64)
+    bad = np.where(flushed & pairs, differ & (got != held), differ)
+    idx = np.nonzero(bad)[0]
+    return {"n": int(idx.size), "flushed": int(flushed.sum()), "examples": [
+        [f"{int(v.view(np.uint64)[i, 0]):#018x}" for v in (x, runtime, got)]
+        for i in idx[:3]]}
+
+
+def _replay_without_wire(spec, operands) -> list:
+    """The replay program with its 64-bit operands and results crossing as
+    they are, through the runtime's own transfers."""
+    import jax
+
+    from repro.core import jaxplan
+
+    impl = (jaxplan._two_level_impl if spec.template == "two_level"
+            else jaxplan._replay_impl)
+    with jax.enable_x64(True):
+        out = jax.jit(impl, static_argnums=0)(spec, *operands)
+    return [np.asarray(a) for a in out[:-1]]
+
+
+def wire_phase(seed: int, rows: int = 20_000) -> list[str]:
+    """The float64 wire format (``jaxplan._Words``) against the runtime's
+    own transfers (``jax.device_put`` in, ``np.asarray`` out) over
+    ``wire_values``: the program's entry read back by the runtime, its
+    exit fed by the runtime, and both ways as a replay moves a value,
+    each bit for bit but where float32 arithmetic flushes a part of the
+    value's pair (``_wire_faults``); then whole replays of non-integer
+    payloads on ``rows`` rows, every output bit for bit.  Logs how far the
+    runtime's round trip moved each kind of value (a TPU holds a float64
+    as a float32 pair).  Returns the failures."""
+    import jax
+
+    from repro.core import SUM, Msgs, TeShuCluster, datacenter, jaxplan
+
+    failures: list[str] = []
+    jaxplan._register_words()
+    with jax.enable_x64(True):
+        entry = jax.jit(jaxplan._unpack)
+        both = jax.jit(lambda w: jaxplan._pack(jaxplan._unpack(w), w))
+        for kind, x in wire_values(seed).items():
+            x = x.reshape(-1, 1)
+            like = jaxplan._to_words(x, 1)
+            runtime = np.asarray(jax.device_put(x))
+            faults = {way: _wire_faults(x, got, runtime, like.pairs)
+                      for way, got in (
+                          ("entry", np.asarray(entry(like))),
+                          ("exit", jaxplan._host_array(jax.jit(
+                              lambda v: jaxplan._pack(v, like))(
+                                  jax.device_put(x)))),
+                          ("both_ways", jaxplan._host_array(both(like))))}
+            moved = runtime != x
+            with np.errstate(invalid="ignore"):
+                err = np.abs(runtime - x)[moved & np.isfinite(x)]
+                ulps = err / np.spacing(np.abs(x[moved & np.isfinite(x)]))
+            _log(phase="wire", kind=kind, values=int(x.size),
+                 pairs=like.pairs, faults=faults,
+                 runtime_moved=int((runtime.view(np.uint64)
+                                    != x.view(np.uint64)).sum()),
+                 runtime_max_ulps=float(ulps.max()) if ulps.size else 0.0)
+            failures += [f"wire/{kind}: the {way} breaks the contract on "
+                         f"{f['n']} of {x.size} values"
+                         for way, f in faults.items() if f["n"]]
+
+    topo = datacenter(workers_per_server=4, servers_per_rack=2, racks=1)
+    workers = list(range(topo.num_workers))
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, rows // 4, rows).astype(np.int64)
+    vals = rng.standard_normal((rows, 4)) * 10.0 ** rng.integers(-3, 7, (rows, 4))
+    parts = np.array_split(np.arange(rows), len(workers))
+    calls = []
+    dispatch = jaxplan._dispatch
+
+    def spy(tracer, fn, spec, operands, *args, **kw):
+        out = dispatch(tracer, fn, spec, operands, *args, **kw)
+        calls.append((spec, operands, out))
+        return out
+    jaxplan._dispatch = spy
+    try:
+        client = TeShuCluster(topo).tenant("wire")
+        for template in TEMPLATES:
+            for _ in range(2):
+                client.shuffle(template, {w: Msgs(keys[p], vals[p])
+                                          for w, p in zip(workers, parts)},
+                               workers, workers, comb_fn=SUM)
+    finally:
+        jaxplan._dispatch = dispatch
+    for spec, operands, out in calls:
+        want = _replay_without_wire(spec, operands)
+        bad = [i for i, (a, b) in enumerate(zip(out, want))
+               if a.dtype != b.dtype or a.tobytes() != b.tobytes()]
+        _log(phase="wire", template=spec.template, rows=rows,
+             outputs=len(out), outputs_differ=bad)
+        if bad or len(out) != len(want):
+            failures.append(f"wire/{spec.template}: outputs {bad} differ from "
+                            "the replay without the wire format")
+    if len(calls) != len(TEMPLATES):   # the first call of each instantiates
+        failures.append(f"wire: {len(calls)} jitted replays, not "
+                        f"{len(TEMPLATES)}")
+    return failures
+
+
+# ---------------------------------------------------------------------------
+# Phase 3 (--four-chip): expert-parallel MoE dispatch over a 2x2 mesh
 # ---------------------------------------------------------------------------
 
 # bf16 keeps an 8-bit significand.  Both paths form the same per-token
@@ -378,6 +552,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chip", action="store_true",
                     help="run only the expert-parallel MoE phase on 4 chips")
+    ap.add_argument("--wire", action="store_true",
+                    help="run only the float64 wire-format phase")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -394,8 +570,11 @@ def main(argv=None) -> int:
     _log(phase="device", compile_cache=use_compile_cache(), **device)
     if args.four_chip:
         failures = four_chip_phase(args.seed)
+    elif args.wire:
+        failures = wire_phase(args.seed)
     else:
-        failures = served_phase(SCALE_FACTOR, BATCH_SCALE_FACTOR, args.seed)
+        failures = (served_phase(SCALE_FACTOR, BATCH_SCALE_FACTOR, args.seed)
+                    + wire_phase(args.seed))
     for f in failures:
         print(f"chip_smoke FAILED: {f}", file=sys.stderr)
     if failures:

@@ -69,7 +69,9 @@ identity is the acceptance contract, and the float32-accumulating Pallas
 kernels (:mod:`repro.kernels.partition`, :mod:`repro.kernels.combine`)
 remain the PART/COMB primitives of the tolerance-validated kernel path
 (``kernels.ops.part`` / ``kernels.ops.combine``, exercised against this
-executor in ``tests/test_jaxplan.py``).
+executor in ``tests/test_jaxplan.py``).  The 64-bit row arrays cross the
+host<->device boundary as 32-bit words (:class:`_Words`) and are 64-bit
+again on either side.
 
 Decline conditions (the service falls back to the vectorized executor,
 which may fall back to threaded):
@@ -93,6 +95,7 @@ See ``docs/jaxplan.md`` for the full lowering rules and executor matrix.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 from collections import OrderedDict
 from typing import NamedTuple
@@ -561,6 +564,175 @@ def _two_level_impl(spec: _PlanSpec, keys, vals, owner):
 
 
 # ---------------------------------------------------------------------------
+# The wire format: 64-bit row arrays cross as 32-bit words
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _Words:
+    """A 64-bit array as 32-bit words, the form in which it crosses between
+    host and device.  A pytree: ``words`` is its leaf; ``shape`` and
+    ``dtype`` (the array's own), ``columns`` (of its rows: 1 for an array
+    with no column axis) and ``pairs`` are static.
+
+    ``words`` is one flat ``uint32`` array of planes: for each column, a
+    low word of every row, then a high word.  The words are the halves of
+    the value's bits, or where ``pairs`` is set (float64 on a TPU) the
+    float32 pair that the TPU holds in place of a float64: the nearest
+    float32 (high) and the nearest float32 to what it leaves (low), as the
+    TPU runtime's own transfers split a float64.  The TPU holds a 64-bit
+    value as two 32-bit halves and lays an ``[N, w]`` array out
+    column-major, so a 64-bit array moved as it is costs the runtime a
+    split (or join) and a relayout on the host, on every call.  A 1-D
+    32-bit array has one linear layout, its planes are contiguous slices on
+    the device (taking every other word there is a gather, about 8 ns a
+    word on a v5e), and the words are put together and taken apart on the
+    device."""
+
+    words: object
+    shape: tuple
+    dtype: str
+    columns: int
+    pairs: bool
+
+
+@functools.cache
+def _register_words() -> None:
+    import jax
+
+    jax.tree_util.register_dataclass(
+        _Words, data_fields=["words"],
+        meta_fields=["shape", "dtype", "columns", "pairs"])
+
+
+def _float_pairs() -> bool:
+    """Whether float64 crosses as float32 pairs: on a TPU, whose bitcast to
+    float64 truncates where its runtime's transfers round."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def _float32_pair(c):
+    """Device side: the float32 pair (low, high) whose sum stands for the
+    float64 array ``c``, split as ``_host_float32_pairs`` splits."""
+    import jax.numpy as jnp
+
+    hi = c.astype(jnp.float32)
+    lo = jnp.where(jnp.isfinite(hi),
+                   (c - hi.astype(jnp.float64)).astype(jnp.float32), 0)
+    return lo, hi
+
+
+def _host_float32_pairs(rows: np.ndarray) -> np.ndarray:
+    """Host side: the float32 pair of each value of the float64 ``rows``
+    as planes ``[column, (low, high), row]``: high the nearest float32, low
+    the nearest float32 to what high leaves (0 where high is not finite),
+    the split of the TPU runtime's own transfers."""
+    planes = np.empty((rows.shape[1], 2, rows.shape[0]), np.float32)
+    rest = np.empty(rows.shape[0])
+    with np.errstate(over="ignore", invalid="ignore"):
+        for j, c in enumerate(rows.T):
+            lo, hi = planes[j]
+            hi[:] = c
+            np.subtract(c, hi, out=rest)
+            lo[:] = rest
+    lo, hi = planes[:, 0], planes[:, 1]
+    if not np.isfinite(hi).all():
+        lo[~np.isfinite(hi)] = 0
+    return planes
+
+
+def _to_words(a: np.ndarray, columns: int):
+    """Host side, on the way in: a 64-bit array with ``columns`` columns as
+    its planes of words (one pass); a narrower one as it is."""
+    if a.dtype.itemsize != 8:
+        return a
+    rows = a.reshape(-1, columns)
+    pairs = a.dtype.kind == "f" and _float_pairs()
+    if pairs:
+        planes = _host_float32_pairs(rows).view(np.uint32)
+    else:
+        planes = np.ascontiguousarray(
+            rows.view(np.uint32).reshape(-1, columns, 2).transpose(1, 2, 0))
+    return _Words(planes.reshape(-1), a.shape, a.dtype.name, columns, pairs)
+
+
+def _host_array(x) -> np.ndarray:
+    """Host side, on the way back: the array a program result stands for,
+    its words joined into one new array."""
+    if not isinstance(x, _Words):
+        return np.asarray(x)
+    out = np.empty(x.shape, x.dtype)
+    k = x.columns
+    rows = out.reshape(-1, k)                               # a view
+    planes = np.asarray(x.words).reshape(k, 2, -1)
+    if x.pairs:
+        for j in range(k):
+            np.add(planes[j, 1].view(np.float32), planes[j, 0].view(np.float32),
+                   out=rows[:, j], dtype=np.float64)
+    else:
+        rows.view(np.uint32).reshape(-1, k, 2)[:] = planes.transpose(2, 0, 1)
+    return out
+
+
+def _unpack(x):
+    """Device side, on entry: the 64-bit array ``x``'s words stand for."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    if not isinstance(x, _Words):
+        return x
+    k = x.columns
+    m = x.words.shape[0] // (2 * k)
+    cols = []
+    for j in range(k):
+        lo = x.words[2 * j * m:(2 * j + 1) * m]
+        hi = x.words[(2 * j + 1) * m:(2 * j + 2) * m]
+        if x.pairs:
+            # where low is 0, high alone: the TPU's float64 sum would lose
+            # a NaN's payload bits and flush a float32 subnormal high part
+            hi, lo = (lax.bitcast_convert_type(w, jnp.float32).astype(jnp.float64)
+                      for w in (hi, lo))
+            cols.append(jnp.where(lo == 0, hi, hi + lo))
+        else:
+            bits = (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
+            cols.append(lax.bitcast_convert_type(bits, jnp.dtype(x.dtype)))
+    return jnp.stack(cols, axis=-1).reshape(x.shape)
+
+
+def _bit_halves(c):
+    """The (low, high) uint32 halves of a 64-bit array's bits."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bits = lax.bitcast_convert_type(c, jnp.uint64)
+    return (bits.astype(jnp.uint32),
+            (bits >> jnp.uint64(32)).astype(jnp.uint32))
+
+
+def _pack(x, like):
+    """Device side, on exit: the result ``x`` in the form its operand
+    ``like`` crossed in (a TPU refuses any bitcast from float64, so there
+    it leaves as the float32 pair the device holds)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    if not isinstance(like, _Words):
+        return x
+    k = like.columns
+    halves = []
+    for c in ([x[..., j] for j in range(k)] if k > 1 else [x]):
+        c = c.reshape(-1)
+        if like.pairs:
+            halves += [lax.bitcast_convert_type(h, jnp.uint32)
+                       for h in _float32_pair(c)]
+        else:
+            halves += _bit_halves(c)
+    return _Words(jnp.concatenate(halves), x.shape, x.dtype.name, k,
+                  like.pairs)
+
+
+# ---------------------------------------------------------------------------
 # The trace cache: one jit instance per (program kind, spec, shape), LRU
 # ---------------------------------------------------------------------------
 
@@ -569,28 +741,38 @@ _REPLAY_LIMIT = 64
 _TRACE_EVICTIONS = 0
 
 
+def _vmapped(impl, spec: _PlanSpec, keys, vals, owner, *shared):
+    """``impl`` over the leading (member) axis of a batch's row operands,
+    the routing tables shared."""
+    import jax
+
+    return jax.vmap(lambda k, v, o: impl(spec, k, v, o, *shared))(
+        keys, vals, owner)
+
+
 def _program(kind: str, sig: tuple, batch: int = 0):
     """The jit instance for one (program kind, static spec, shape signature),
-    creating and LRU-evicting under the replay-cache limit."""
+    creating and LRU-evicting under the replay-cache limit.  It takes the
+    rows' keys and values in the wire format (``_Words`` where they are
+    64-bit) and gives them back in the form they came in; a batched one
+    vmaps the replay over the row operands' leading axis."""
     global _TRACE_EVICTIONS
     import jax
 
+    _register_words()
     key = (kind, batch, sig)
     fn = _PROGRAMS.get(key)
     if fn is None:
         impl = _two_level_impl if kind == "two_level" else _replay_impl
-
         if batch:
-            def entry(spec, keys, vals, owner, *shared):
-                return jax.vmap(
-                    lambda k, v, o: impl(spec, k, v, o, *shared))(
-                        keys, vals, owner)
-        else:
-            # a per-program closure: jit wrappers over the SAME function
-            # share jax's compilation cache, which would make each entry's
-            # _cache_size() report the union and break eviction accounting
-            def entry(spec, *operands, _impl=impl):
-                return _impl(spec, *operands)
+            impl = functools.partial(_vmapped, impl)
+
+        # a per-program closure: jit wrappers over the SAME function share
+        # jax's compilation cache, which would make each entry's
+        # _cache_size() report the union and break eviction accounting
+        def entry(spec, keys, vals, *operands, _impl=impl):
+            out = _impl(spec, _unpack(keys), _unpack(vals), *operands)
+            return (_pack(out[0], keys), _pack(out[1], vals), *out[2:])
         fn = jax.jit(entry, static_argnames=("spec",))
         _PROGRAMS[key] = fn
     _PROGRAMS.move_to_end(key)
@@ -1060,24 +1242,36 @@ def _dispatch(tracer, fn, spec: _PlanSpec, operands: tuple, ids: dict,
     on the device), ``jit_replay`` (dispatch until the outputs are ready on
     the device; ``compiled`` says whether this program traced anew,
     ``fold_rounds`` how many rounds its COMB folds ran, per member of a
-    batch) and ``to_host`` (the outputs back).  With tracing off the
-    program takes the host arrays and nothing waits but the copy back."""
+    batch) and ``to_host`` (the outputs back); each transfer includes its
+    host side of the wire format, and ``words32`` on it counts the 64-bit
+    arrays that crossed as uint32 words.  With tracing off the program
+    takes the host arrays and nothing waits but the copy back."""
     import jax
 
+    def wire(operands):
+        keys, vals, *rest = operands
+        return (_to_words(keys, 1), _to_words(vals, vals.shape[-1]), *rest)
     if not tracer.enabled:
         with jax.enable_x64(True):
-            out = fn(spec, *operands)
-        return tuple(np.asarray(a) for a in out[:-1])
+            out = fn(spec, *wire(operands))
+        return tuple(_host_array(a) for a in out[:-1])
     with jax.enable_x64(True):
-        with tracer.span("to_device", **ids, **attrs):
+        with tracer.span("to_device", **ids, **attrs) as sp:
+            operands = wire(operands)
+            sp.set(words32=_count_words(operands))
             operands = jax.block_until_ready(jax.device_put(operands))
         with tracer.span("jit_replay", **ids, rows=rows, **attrs) as sp:
             traces = fn._cache_size()
             out = jax.block_until_ready(fn(spec, *operands))
             sp.set(compiled=fn._cache_size() > traces,
                    fold_rounds=np.asarray(out[-1]).tolist())
-    with tracer.span("to_host", **ids, **attrs):
-        return tuple(np.asarray(a) for a in out[:-1])
+    with tracer.span("to_host", **ids, **attrs,
+                     words32=_count_words(out[:-1])):
+        return tuple(_host_array(a) for a in out[:-1])
+
+
+def _count_words(arrays) -> int:
+    return sum(isinstance(a, _Words) for a in arrays)
 
 
 def _charge_replay(ledger, topo, args: ShuffleArgs, low: JaxLowering,

@@ -35,6 +35,39 @@ def test_served_phase_passes_on_cpu(monkeypatch, capsys):
     assert [r["batched"] for r in recs if "ticket" in r] == [True, True]
 
 
+def _wire_phase_on_cpu(monkeypatch, capsys):
+    import repro.core.service as service
+    monkeypatch.setattr(service, "default_executor", lambda: "jax")
+    monkeypatch.setattr(chip_smoke, "WIRE_VALUES", 3000)
+    failures = chip_smoke.wire_phase(seed=4, rows=1500)
+    recs = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    return failures, [r for r in recs if r["phase"] == "wire"]
+
+
+def test_wire_phase_passes_on_cpu(monkeypatch, capsys):
+    failures, recs = _wire_phase_on_cpu(monkeypatch, capsys)
+    assert failures == []
+    kinds = [r for r in recs if "kind" in r]
+    assert [r["kind"] for r in kinds] == list(chip_smoke.wire_values(0))
+    assert all(f["n"] == 0 for r in kinds for f in r["faults"].values())
+    assert all(r["runtime_moved"] == 0 and not r["pairs"] for r in kinds)
+    assert [r["template"] for r in recs if "template" in r] == list(
+        chip_smoke.TEMPLATES)
+
+
+def test_wire_phase_catches_a_split_unlike_the_runtimes(monkeypatch, capsys):
+    """Forced onto the CPU, whose runtime moves float64 as it is, the TPU's
+    float32 pairs round what the runtime does not: the phase reports the
+    entry, the exit and the whole replays."""
+    from repro.core import jaxplan
+    monkeypatch.setattr(jaxplan, "_float_pairs", lambda: True)
+    failures, _ = _wire_phase_on_cpu(monkeypatch, capsys)
+    for what in ("general: the entry", "general: the exit",
+                 "general: the both_ways", "hundredths: the entry",
+                 "vanilla_push: outputs [1]", "network_aware: outputs [1]"):
+        assert any(what in f for f in failures), what
+
+
 def test_lineitem_follows_dbgen():
     keys, vals = chip_smoke.lineitem(0.01, seed=1)
     orders, lines = np.unique(keys, return_counts=True)

@@ -24,17 +24,23 @@ What the conformance matrix (test_conformance.py) does not already pin:
   ``segment_combine``) against the bit-exact default plane;
 * the COMB fold in rounds by rank against the row-serial ``lax.scan`` fold
   it replaced, byte for byte, alone and under ``vmap``, and the
-  ``fold_rounds`` it reports.
+  ``fold_rounds`` it reports;
+* the wire format of the replay's 64-bit keys and payloads (uint32 words,
+  as their bits, or on a TPU as float32 pairs): edge bit patterns arrive
+  unchanged, the pairs hold what a TPU holds, every template replays
+  through either form, and the transfers count the words.
 """
 import math
 
 import numpy as np
 import pytest
 
-from conformance import (assert_identical, conformance_case, copy_bufs,
-                         make_bufs, make_topology, service_for, workers_for)
+from conformance import (ALL_TEMPLATES, assert_identical, conformance_case,
+                         copy_bufs, make_bufs, make_topology, service_for,
+                         workers_for)
 from repro.core import (COMBINERS, SUM, Msgs, PartFn, TeShuCluster,
                         TeShuService, datacenter)
+from repro.core import jaxplan
 from repro.core.jaxplan import (_combine, kernel_global_stage, lower_plan,
                                 plan_decline, replay_cache_limit,
                                 replay_cache_size, set_kernel_plane,
@@ -753,3 +759,214 @@ def test_jit_replay_reports_fold_rounds():
     (jit,) = [s for s in cl.spans() if s["name"] == "jit_replay"]
     assert jit["attrs"]["batch"] == 2
     assert jit["attrs"]["fold_rounds"] == [most, most]
+
+
+# ---------------------------------------------------------------------------
+# the wire format: 64-bit row arrays cross host<->device as uint32 words
+# ---------------------------------------------------------------------------
+
+# NaNs with payload bits (signalling and quiet, both signs), -0.0, +0.0,
+# +-inf, the least and the greatest subnormal, the greatest double, 1.0
+EDGE_BITS = np.array([0x7FF0000000000001, 0xFFF8000000000123,
+                      0x8000000000000000, 0x0, 0x7FF0000000000000,
+                      0xFFF0000000000000, 0x1, 0x800FFFFFFFFFFFFF,
+                      0x7FEFFFFFFFFFFFFF, 0x3FF0000000000000], np.uint64)
+
+
+def _edge_bufs(workers):
+    """``make_bufs``' rows, and on every worker rows whose payloads are the
+    edge bit patterns, each under a key of its own: negative keys, and on
+    the first worker +-(2**63 - 1).  No COMB folds a key that occurs once,
+    so these payloads must arrive bit for bit."""
+    edge = EDGE_BITS.view(np.float64)
+    out = {}
+    for i, (w, m) in enumerate(make_bufs(workers, "zipf").items()):
+        keys = -(np.arange(edge.size, dtype=np.int64) + 1 + 100 * i) * 7919
+        if i == 0:
+            keys[:2] = (2**63 - 1, -(2**63 - 1))
+        vals = np.stack([np.roll(edge, i), np.roll(edge, i + 3)], axis=1)
+        out[w] = Msgs(np.concatenate([m.keys, keys]),
+                      np.concatenate([m.vals, vals]))
+    return out
+
+
+def _assert_bits_identical(a: dict, b: dict):
+    """Per destination, keys and payload bits equal in physical row order
+    (``assert_identical`` takes NaN for NaN and -0.0 for 0.0)."""
+    assert set(a) == set(b)
+    for w in a:
+        assert np.array_equal(a[w].keys, b[w].keys)
+        assert np.array_equal(a[w].vals.view(np.uint64),
+                              b[w].vals.view(np.uint64))
+
+
+def _parent_outputs(spec, operands, batch):
+    """The replay program as it ran with 64-bit operands and results
+    crossing as they are, on the same host operands."""
+    import jax
+
+    impl = (jaxplan._two_level_impl if spec.template == "two_level"
+            else jaxplan._replay_impl)
+
+    def run(spec, keys, vals, owner, *shared):
+        if batch:
+            return jax.vmap(lambda k, v, o: impl(spec, k, v, o, *shared))(
+                keys, vals, owner)
+        return impl(spec, keys, vals, owner, *shared)
+    with jax.enable_x64(True):
+        out = jax.jit(run, static_argnums=0)(spec, *operands)
+    return [np.asarray(a) for a in out[:-1]]
+
+
+@pytest.mark.parametrize("path", ["serial", "batched"])
+@pytest.mark.parametrize("template", ALL_TEMPLATES)
+def test_edge_bit_patterns_cross_the_wire_unchanged(template, path,
+                                                     monkeypatch):
+    """Keys at the int64 extremes and below zero, and payloads of NaNs with
+    payload bits, signed zeros, infinities and subnormals, replayed serially
+    and as a batch: the delivered buffers match the vectorized executor bit
+    for bit, and every program result (keys, vals, owner, alive, the flow
+    counts) reaches the host as the program computed it without the wire
+    format."""
+    workers = workers_for(template)
+    bufs = _edge_bufs(workers)
+    calls = []
+    dispatch = jaxplan._dispatch
+
+    def spy(tracer, fn, spec, operands, ids, rows, **attrs):
+        out = dispatch(tracer, fn, spec, operands, ids, rows, **attrs)
+        calls.append((spec, operands, attrs.get("batch", 0), out))
+        return out
+    monkeypatch.setattr(jaxplan, "_dispatch", spy)
+    if path == "serial":
+        got = [_run_twice(_jax_service(), template, bufs, workers,
+                          comb_fn=SUM)]
+    else:
+        cl = TeShuCluster(make_topology(), execution="auto", executor="jax")
+        tenants = [cl.tenant(f"t{i}") for i in range(2)]
+        for t in tenants:
+            _run_twice(t, template, bufs, workers, comb_fn=SUM)
+        tickets = [t.submit(template, copy_bufs(bufs), workers, workers,
+                            comb_fn=SUM) for t in tenants]
+        done = cl.run_pending()
+        got = [done[tk] for tk in tickets]
+        assert all(r.batched for r in got)
+    ref = _run_twice(service_for("vectorized"), template, bufs, workers,
+                     comb_fn=SUM)
+    for res in got:
+        assert res.engine == "jax" and res.fallback_reason is None
+        _assert_bits_identical(res.bufs, ref.bufs)
+        delivered = np.concatenate([m.vals for m in res.bufs.values()])
+        assert set(EDGE_BITS.tolist()) <= set(
+            delivered.view(np.uint64).ravel().tolist())
+    spec, operands, batch, out = calls[-1]
+    assert batch == (2 if path == "batched" else 0)
+    want = _parent_outputs(spec, operands, batch)
+    assert len(out) == len(want)
+    for a, b in zip(out, want):      # owner and alive among them
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+def test_transfers_count_their_uint32_words():
+    """With tracing on, ``to_device`` and ``to_host`` carry ``words32``:
+    the 64-bit arrays that crossed as uint32 words, keys and payloads each
+    way, for a serial replay and a batched dispatch alike."""
+    sv = _jax_service(tracing=True)
+    bufs = make_bufs(WORKERS, "zipf")
+    _run_twice(sv, "vanilla_push", bufs, WORKERS, comb_fn=SUM, shuffle_id=81)
+    spans = [s for s in sv.spans(81) if s["name"] in ("to_device", "to_host")]
+    assert [(s["name"], s["attrs"]["words32"]) for s in spans] == [
+        ("to_device", 2), ("to_host", 2)]
+
+    cl = TeShuCluster(make_topology(), execution="auto", executor="jax",
+                      tracing=True)
+    tenants = [cl.tenant(f"t{i}") for i in range(2)]
+    for t in tenants:
+        _run_twice(t, "vanilla_push", bufs, WORKERS, comb_fn=SUM)
+    cl.obs.tracer.clear()
+    for t in tenants:
+        t.submit("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                 comb_fn=SUM)
+    cl.run_pending()
+    spans = [s for s in cl.spans() if s["name"] in ("to_device", "to_host")]
+    assert [(s["name"], s["attrs"]["batch"], s["attrs"]["words32"])
+            for s in spans] == [("to_device", 2, 2), ("to_host", 2, 2)]
+
+
+def _wire_round_trip(x: np.ndarray, columns: int) -> np.ndarray:
+    """``x`` through the wire format both ways: to words on the host, to
+    64-bit on the device and back to words, joined on the host."""
+    import jax
+
+    jaxplan._register_words()
+    words = jaxplan._to_words(x, columns)
+    with jax.enable_x64(True):
+        back = jax.jit(lambda w: jaxplan._pack(jaxplan._unpack(w), w))(words)
+    assert isinstance(back, jaxplan._Words) and back.pairs == words.pairs
+    got = jaxplan._host_array(back)
+    assert got.flags.c_contiguous and got.dtype == x.dtype
+    return got
+
+
+def test_float32_pairs_cross_what_a_tpu_holds(monkeypatch):
+    """Where float64 crosses as float32 pairs (on a TPU, which holds a
+    float64 as one), the host splits each value into its nearest float32
+    and the nearest float32 to the rest, the device sums the pair and
+    splits its result the same way, and the host sums that pair.  Here on
+    the CPU: a value the pair holds exactly (integers below 2**48, 0.0,
+    infinities, a pair 40 binades apart) comes back bit for bit, any other
+    as the sum of its pair (-0.0 as 0.0, as the TPU runtime's own split
+    and join give it), and int64 keys still cross as their bits."""
+    monkeypatch.setattr(jaxplan, "_float_pairs", lambda: True)
+    rng = np.random.default_rng(3)
+    exact = np.concatenate([
+        rng.integers(-2**48, 2**48, 5000).astype(np.float64),
+        [0.0, np.inf, -np.inf, 2.0**100 + 2.0**60, -(2.0**-90), 1.0]])
+    got = _wire_round_trip(exact.reshape(-1, 2), 2)
+    assert np.array_equal(got.view(np.uint64).ravel(), exact.view(np.uint64))
+    got = _wire_round_trip(np.array([[-0.0]]), 1)
+    assert got.view(np.uint64)[0, 0] == 0
+
+    x = rng.standard_normal((4000, 3)) * 10.0 ** rng.integers(-6, 9, (4000, 3))
+    hi = x.astype(np.float32)
+    pair = hi.astype(np.float64) + (x - hi).astype(np.float32)
+    got = _wire_round_trip(x, 3)
+    assert np.array_equal(got, pair) and not np.array_equal(got, x)
+    assert np.isnan(_wire_round_trip(np.full((3, 1), np.nan), 1)).all()
+
+    keys = np.array([2**63 - 1, -(2**63 - 1), -1, 0], np.int64)
+    got = _wire_round_trip(keys, 1)
+    assert np.array_equal(got, keys)
+
+
+@pytest.mark.parametrize("path", ["serial", "batched"])
+def test_replay_with_float32_pairs_matches_vectorized(path, monkeypatch):
+    """Every template replays with its payloads crossing as float32 pairs,
+    serially and as a batch, and delivers the vectorized executor's buffers
+    bit for bit where the pairs hold every value exactly (integer payloads
+    whose sums stay below 2**48)."""
+    monkeypatch.setattr(jaxplan, "_float_pairs", lambda: True)
+    for template in ALL_TEMPLATES:
+        workers = workers_for(template)
+        bufs = {w: Msgs(m.keys, np.floor(m.vals * 2**30))
+                for w, m in make_bufs(workers, "zipf").items()}
+        if path == "serial":
+            got = [_run_twice(_jax_service(), template, bufs, workers,
+                              comb_fn=SUM)]
+        else:
+            cl = TeShuCluster(make_topology(), execution="auto",
+                              executor="jax")
+            tenants = [cl.tenant(f"t{i}") for i in range(2)]
+            for t in tenants:
+                _run_twice(t, template, bufs, workers, comb_fn=SUM)
+            tickets = [t.submit(template, copy_bufs(bufs), workers, workers,
+                                comb_fn=SUM) for t in tenants]
+            done = cl.run_pending()
+            got = [done[tk] for tk in tickets]
+            assert all(r.batched for r in got)
+        ref = _run_twice(service_for("vectorized"), template, bufs, workers,
+                         comb_fn=SUM)
+        for res in got:
+            assert res.engine == "jax" and res.fallback_reason is None
+            _assert_bits_identical(res.bufs, ref.bufs)

@@ -74,7 +74,11 @@ def test_partition_permute_compiles_at_plane_shape(one_chip):
 @pytest.mark.parametrize("template", ["vanilla_push", "network_aware"])
 def test_replay_program_compiles_x64(one_chip, template):
     """The regular templates' jitted replay, 64-bit keys and payloads, on the
-    16-worker three-level datacenter the chip smoke serves."""
+    16-worker three-level datacenter the chip smoke serves.  The 64-bit row
+    arrays cross as 32-bit words: no int64 or float64 row array is a
+    parameter or result of the compiled program, and each of the two is one
+    1-D uint32 array (one linear layout, nothing for the host to relayout):
+    the keys' bits, the payloads' float32 pairs."""
     ns, levels, rows, width = 16, 3, 4096, 4
     spec = jaxplan._PlanSpec(template=template, comb="sum", part=("hash",),
                              initial_comb=template == "network_aware",
@@ -84,11 +88,23 @@ def test_replay_program_compiles_x64(one_chip, template):
               ((ns, ns), jnp.int32), ((0,), jnp.int64), ((0, 1), jnp.int32),
               ((0,), jnp.int32)]
     with jax.enable_x64(True):
-        operands = [_sds((rows,), jnp.int64, one_chip),
-                    _sds((rows, width), jnp.float64, one_chip),
-                    _sds((rows,), jnp.int32, one_chip)]
+        fn = jaxplan._program("scan", (spec, (rows,), (rows, width),
+                                       tuple(s for s, _ in shared)))
+        operands = [
+            jaxplan._Words(_sds((rows * 2,), jnp.uint32, one_chip),
+                           (rows,), "int64", 1, False),
+            jaxplan._Words(_sds((rows * width * 2,), jnp.uint32, one_chip),
+                           (rows, width), "float64", width, True),
+            _sds((rows,), jnp.int32, one_chip)]
         operands += [_sds(s, d, one_chip) for s, d in shared]
-        sig = (spec, (rows,), (rows, width), tuple(s for s, _ in shared))
-        compiled = jaxplan._program("scan", sig).lower(spec, *operands).compile()
-    out_dtypes = [a.dtype for a in jax.tree.leaves(compiled.out_info)]
-    assert out_dtypes[:2] == [jnp.int64, jnp.float64]
+        compiled = fn.lower(spec, *operands).compile()
+    layout = compiled.as_text().split("entry_computation_layout=")[1]
+    layout = layout.split("\n")[0]
+    assert f"s64[{rows}]" not in layout and f"f64[{rows}," not in layout
+    keys, vals = compiled.out_info[:2]
+    assert (keys.shape, keys.dtype, vals.shape, vals.dtype) == (
+        (rows,), "int64", (rows, width), "float64")
+    assert keys.words.shape == (2 * rows,) and keys.words.dtype == jnp.uint32
+    assert vals.words.shape == (2 * rows * width,)
+    assert vals.words.dtype == jnp.uint32
+    assert (keys.pairs, vals.pairs) == (False, True)
