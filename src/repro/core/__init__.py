@@ -37,8 +37,8 @@ from .templates import (TEMPLATES, ShuffleResult, ShuffleTemplate, register_temp
 from .topology import (NetworkTopology, Level, datacenter, degrade_links, fat_tree,
                        from_mesh_axes, multipod_dcn, roofline_times, dominant_term,
                        roofline_fraction)
-from .vectorized import (can_vectorize, combine_msgs, run_shuffle_vectorized,
-                         set_comb_backend, vectorize_decline)
+from .vectorized import (can_vectorize, run_shuffle_vectorized,
+                         vectorize_decline)
 
 __all__ = [
     "EffCost", "compute_eff_cost", "eff_cost_from_ratio", "reduction_drift",
@@ -67,8 +67,8 @@ __all__ = [
     "ShuffleTemplate", "register_template", "run_shuffle", "template_loc",
     "NetworkTopology", "Level", "datacenter", "degrade_links", "fat_tree",
     "from_mesh_axes", "multipod_dcn", "roofline_times", "dominant_term",
-    "roofline_fraction", "can_vectorize", "combine_msgs",
-    "run_shuffle_vectorized", "set_comb_backend", "vectorize_decline",
+    "roofline_fraction", "can_vectorize",
+    "run_shuffle_vectorized", "vectorize_decline",
     "CheckpointStore", "FailureDetector", "FailureReport", "RecoveryContext",
     "RecoveryCoordinator", "SpeculationPolicy", "SpeculativeTask",
     "StreamCheckpoint",
@@ -77,7 +77,7 @@ __all__ = [
     "FlightRecorder", "MetricsRegistry", "NULL_TRACER", "NullTracer",
     "Observability", "ShuffleReport", "build_report",
     "JAX_TEMPLATES", "JaxLowering", "decline_reason", "lower_plan",
-    "plan_decline", "try_run_jax", "replay_cache_size", "set_kernel_plane",
+    "plan_decline", "try_run_jax", "replay_cache_size",
 ]
 
 # The jitted executor is resolved lazily: importing repro.core must not pull
@@ -85,7 +85,7 @@ __all__ = [
 # itself only imports repro.core.jaxplan on the first executor="jax" call.
 _JAXPLAN_EXPORTS = ("JAX_TEMPLATES", "JaxLowering", "decline_reason",
                     "lower_plan", "plan_decline", "try_run_jax",
-                    "replay_cache_size", "set_kernel_plane")
+                    "replay_cache_size")
 
 
 def __getattr__(name: str):

@@ -65,11 +65,7 @@ per-tenant byte/cost lanes equal serial charges while modelled time pays
 the barrier once.
 
 Precision: the hot path runs in float64 under ``jax.enable_x64`` — byte
-identity is the acceptance contract, and the float32-accumulating Pallas
-kernels (:mod:`repro.kernels.partition`, :mod:`repro.kernels.combine`)
-remain the PART/COMB primitives of the tolerance-validated kernel path
-(``kernels.ops.part`` / ``kernels.ops.combine``, exercised against this
-executor in ``tests/test_jaxplan.py``).  The 64-bit row arrays cross the
+identity is the acceptance contract.  The 64-bit row arrays cross the
 host<->device boundary as 32-bit words (:class:`_Words`) and are 64-bit
 again on either side.
 
@@ -107,7 +103,7 @@ from .plancache import CompiledPlan, attach_lowering, get_lowering
 from .primitives import LocalCluster, ShuffleArgs
 from .skew import owner_merge_plan, scatter_tables
 from .templates import ShuffleResult, aggregate_observed
-from .vectorized import VECTORIZABLE, combine_msgs
+from .vectorized import VECTORIZABLE
 
 # Every built-in template lowers: the four regular replays share the rolled
 # scan program; bruck rides the same program behind a lower-time routing
@@ -824,79 +820,6 @@ def trace_evictions() -> int:
 
 
 # ---------------------------------------------------------------------------
-# The Pallas kernel plane (explicit opt-in, off on every backend by default)
-# ---------------------------------------------------------------------------
-
-# Off by default: segment_combine's [ndst * distinct keys, block_d] VMEM
-# accumulator runs out of VMEM at the key cardinalities real aggregations
-# have, and partition_permute with num_out = N takes (N / block)^2 grid
-# steps.  The replay's own exact payloads are the result.
-_KERNEL_PLANE = False
-
-
-def kernel_plane_enabled() -> bool:
-    """Whether SUM replays re-fold their payloads through the Pallas kernels
-    (only after an explicit ``set_kernel_plane(True)``)."""
-    return _KERNEL_PLANE
-
-
-def set_kernel_plane(enabled: bool) -> bool:
-    """Opt SUM replays' global PART/COMB into the Pallas MXU kernels:
-    :func:`repro.kernels.partition.partition_permute` routes rows to their
-    destination-major positions (PART as a one-hot permutation matmul) and
-    :func:`repro.kernels.combine.segment_combine` folds per-(destination,
-    key) segments (COMB as an accumulating one-hot matmul).
-
-    The kernels accumulate in float32, so output payloads then match the
-    exact plane only to float32 tolerance; routing decisions, output key
-    sets and all ledger charges still come from the exact program, and
-    skew-scattered replays keep exact payloads unconditionally.  Returns the
-    previous setting so callers can restore it.
-    """
-    global _KERNEL_PLANE
-    prev = _KERNEL_PLANE
-    _KERNEL_PLANE = bool(enabled)
-    return prev
-
-
-def kernel_global_stage(part_fn, keys: np.ndarray, vals: np.ndarray,
-                        ndst: int) -> list[tuple[np.ndarray, np.ndarray]]:
-    """The fused global exchange+fold of a SUM replay on the Pallas kernels.
-
-    SUM's per-(destination, key) totals are invariant under the hierarchy's
-    pre-combines, so the whole replay collapses to one PART + one COMB over
-    the stacked inputs: ``partition_permute`` moves every row to its
-    destination-major slot (a pure permutation — each output row has exactly
-    one contributor), then ``segment_combine`` folds the contiguous
-    (destination, key) segments.  Returns ``[(keys, vals), ...]`` per
-    destination with keys ascending — the same key order the exact combined
-    replay produces.
-    """
-    import jax.numpy as jnp
-
-    from repro.kernels.combine import segment_combine
-    from repro.kernels.partition import partition_permute
-
-    slot = part_fn.assign(keys, ndst)              # the plan's real partFunc
-    uniq, inv = np.unique(keys, return_inverse=True)
-    nk = int(uniq.size)
-    seg_of_row = slot.astype(np.int64) * nk + inv  # (dst, key) segment id
-    order = np.argsort(seg_of_row, kind="stable")  # destination-major layout
-    pos = np.empty(len(keys), np.int32)
-    pos[order] = np.arange(len(keys), dtype=np.int32)
-    routed = partition_permute(jnp.asarray(pos),   # PART: one-hot permutation
-                               jnp.asarray(vals, dtype=jnp.float32),
-                               num_out=len(keys))
-    folded = segment_combine(                      # COMB: per-segment fold
-        jnp.asarray(seg_of_row[order], dtype=jnp.int32), routed,
-        num_segments=ndst * nk)
-    dense = np.asarray(folded, dtype=np.float64).reshape(ndst, nk, -1)
-    present = np.zeros((ndst, nk), bool)
-    present[slot, inv] = True
-    return [(uniq[present[d]], dense[d][present[d]]) for d in range(ndst)]
-
-
-# ---------------------------------------------------------------------------
 # The executor
 # ---------------------------------------------------------------------------
 
@@ -1082,6 +1005,17 @@ class _BatchSlot:
             self.handle.settle(ledger, consumed)
 
 
+def _stage_rows(srcs, per_w: list, low: JaxLowering) -> tuple:
+    """One call's row operands from its sources' buffers ``per_w``, in
+    source order: keys, values, and each row's owner (its source's
+    position)."""
+    keys = np.concatenate([m.keys for m in per_w])
+    vals = np.concatenate([np.ascontiguousarray(m.vals) for m in per_w])
+    owner = np.concatenate([np.full(m.n, low.src_pos[w], np.int32)
+                            for w, m in zip(srcs, per_w)])
+    return keys, vals, owner
+
+
 def batch_signature(cluster: LocalCluster, args: ShuffleArgs,
                     bufs: dict[int, Msgs]):
     """Hashable grouping key for batched dispatch, or None when this
@@ -1125,16 +1059,10 @@ def prepare_batch(cluster: LocalCluster, members) -> "list[_BatchSlot] | None":
     width = next((m.width for m in bufs0.values() if m.n), 1)
     with tracer.span("batch_dispatch", members=len(members)) as sp:
         with tracer.span("stage_batch"):
-            keys, vals, owner = [], [], []
-            for a, b in members:
-                per_w = [b.get(w, Msgs.empty(width)) for w in a.srcs]
-                keys.append(np.concatenate([m.keys for m in per_w]))
-                vals.append(np.concatenate([np.ascontiguousarray(m.vals)
-                                            for m in per_w]))
-                owner.append(np.concatenate(
-                    [np.full(m.n, low.src_pos[w], np.int32)
-                     for w, m in zip(a.srcs, per_w)]))
-            keys, vals, owner = np.stack(keys), np.stack(vals), np.stack(owner)
+            staged = [_stage_rows(a.srcs, [b.get(w, Msgs.empty(width))
+                                            for w in a.srcs], low)
+                      for a, b in members]
+            keys, vals, owner = (np.stack(x) for x in zip(*staged))
         sp.set(rows=int(keys.size))
         kind, shared = _program_inputs(spec, low)
         sig = (spec, keys.shape[1:], vals.shape[1:],
@@ -1237,34 +1165,31 @@ def _charge_two_level(ledger, topo, args, low, gmoved_init, post1, p3moved,
 def _dispatch(tracer, fn, spec: _PlanSpec, operands: tuple, ids: dict,
               rows: int, **attrs) -> tuple:
     """Run one replay program on host ``operands``; its outputs as host
-    arrays, less the last (the fold rounds).  With tracing on, the stages
-    are spans of their own, each waited for: ``to_device`` (the inputs put
-    on the device), ``jit_replay`` (dispatch until the outputs are ready on
-    the device; ``compiled`` says whether this program traced anew,
-    ``fold_rounds`` how many rounds its COMB folds ran, per member of a
-    batch) and ``to_host`` (the outputs back); each transfer includes its
-    host side of the wire format, and ``words32`` on it counts the 64-bit
-    arrays that crossed as uint32 words.  With tracing off the program
-    takes the host arrays and nothing waits but the copy back."""
+    arrays, less the last (the fold rounds).  Traced or not, the call is
+    the same three stages, each a span of its own: ``to_device`` (the host
+    side of the wire format; ``words32`` counts the 64-bit arrays that
+    cross as uint32 words), ``jit_replay`` (the program called on the host
+    arrays, their copy to the device included) and ``to_host`` (each
+    output copied back and joined).  Tracing adds measurement only: a wait
+    for the outputs, which ``to_host``'s first read would make anyway, so
+    ``jit_replay`` closes when the device is done; ``compiled``, whether
+    this program traced anew; and ``fold_rounds``, how many rounds its
+    COMB folds ran, per member of a batch."""
     import jax
 
-    def wire(operands):
-        keys, vals, *rest = operands
-        return (_to_words(keys, 1), _to_words(vals, vals.shape[-1]), *rest)
-    if not tracer.enabled:
-        with jax.enable_x64(True):
-            out = fn(spec, *wire(operands))
-        return tuple(_host_array(a) for a in out[:-1])
+    keys, vals, *rest = operands
     with jax.enable_x64(True):
         with tracer.span("to_device", **ids, **attrs) as sp:
-            operands = wire(operands)
+            operands = (_to_words(keys, 1), _to_words(vals, vals.shape[-1]),
+                        *rest)
             sp.set(words32=_count_words(operands))
-            operands = jax.block_until_ready(jax.device_put(operands))
         with tracer.span("jit_replay", **ids, rows=rows, **attrs) as sp:
             traces = fn._cache_size()
-            out = jax.block_until_ready(fn(spec, *operands))
-            sp.set(compiled=fn._cache_size() > traces,
-                   fold_rounds=np.asarray(out[-1]).tolist())
+            out = fn(spec, *operands)
+            if tracer.enabled:
+                jax.block_until_ready(out)
+                sp.set(compiled=fn._cache_size() > traces,
+                       fold_rounds=np.asarray(out[-1]).tolist())
     with tracer.span("to_host", **ids, **attrs,
                      words32=_count_words(out[:-1])):
         return tuple(_host_array(a) for a in out[:-1])
@@ -1377,7 +1302,7 @@ def _owner_merge(ledger, topo, args: ShuffleArgs, out_bufs: dict) -> None:
         batch = Msgs.concat([out_bufs[owner_w]] + got)
         if args.comb_fn is not None:
             ledger.charge_combine(owner_w, batch.nbytes, tenant=args.tenant)
-            out_bufs[owner_w] = combine_msgs(args.comb_fn, batch)
+            out_bufs[owner_w] = args.comb_fn(batch)
         else:
             out_bufs[owner_w] = batch
 
@@ -1406,11 +1331,8 @@ def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
     # ---- the compiled data plane ------------------------------------------
     with tracer.span("stage_inputs", **ids):
         per_w = [bufs.get(w, Msgs.empty(width)) for w in srcs]
-        keys = np.concatenate([m.keys for m in per_w])
-        vals = np.concatenate([np.ascontiguousarray(m.vals) for m in per_w])
         if batch_slot is None:
-            owner = np.concatenate([np.full(m.n, low.src_pos[w], np.int32)
-                                    for w, m in zip(srcs, per_w)])
+            keys, vals, owner = _stage_rows(srcs, per_w, low)
             kind, shared = _program_inputs(spec, low)
             fn = _program(kind, (spec, keys.shape, vals.shape,
                                  tuple(a.shape for a in shared)))
@@ -1431,15 +1353,6 @@ def _run_lowered(cluster, args: ShuffleArgs, bufs: dict[int, Msgs],
             mask = (f_owner == low.dst_pos[d]) & f_alive
             out_bufs[d] = Msgs(f_keys[mask],
                                f_vals[mask].reshape(-1, width))
-        if (kernel_plane_enabled() and spec.comb == "sum" and not spec.skew
-                and spec.template not in ("bruck", "two_level")):
-            # Pallas plane (opt-in): same routing and key sets, payloads
-            # re-folded on the MXU kernels (float32 accumulation — see
-            # set_kernel_plane)
-            for d, (kk, vv) in zip(dsts,
-                                   kernel_global_stage(args.part_fn, keys,
-                                                       vals, len(dsts))):
-                out_bufs[d] = Msgs(kk, vv.reshape(-1, width))
     if spec.skew:
         with tracer.span("owner_merge", **ids):
             _owner_merge(ledger, topo, args, out_bufs)

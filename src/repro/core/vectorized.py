@@ -13,9 +13,7 @@ This module executes a cached plan single-threaded with batched numpy:
   (:func:`repro.core.messages.partition`), never a per-message Python loop;
 * ledger charges are folded per worker with ``CostLedger.charge_transfers``
   (one vectorized bincount + one lock acquisition instead of one call per peer);
-* combines remain the vectorized sort + ``ufunc.reduceat`` — or, opt-in via
-  :func:`set_comb_backend`, the Pallas MXU segment-combine kernel
-  (:mod:`repro.kernels.combine`) for SUM combiners.
+* combines remain the vectorized sort + ``ufunc.reduceat``.
 
 Equivalence contract: for the supported templates the output buffers are
 *byte-identical* to the threaded plan path (same partition functions, same concat
@@ -41,49 +39,13 @@ from __future__ import annotations
 
 import numpy as np
 
-from .messages import Combiner, Msgs, partition
+from .messages import Msgs, partition
 from .primitives import LocalCluster, ShuffleAborted, ShuffleArgs
 from .skew import owner_merge_plan, scatter_part_fn
 from .templates import ShuffleResult, aggregate_observed
 
 VECTORIZABLE = frozenset(
     {"vanilla_push", "vanilla_pull", "coordinated", "network_aware"})
-
-_COMB_BACKEND = "numpy"
-
-
-def set_comb_backend(name: str) -> str:
-    """Select the combine backend: ``"numpy"`` (default) or ``"pallas"``.
-
-    The Pallas path routes SUM combines through the TPU segment-combine kernel
-    (interpret mode on CPU; compiled natively on TPU).  It accumulates in float32,
-    so it is opt-in: the default backend keeps bit-exact float64 semantics.
-    Returns the previous backend (so callers can restore it).
-    """
-    global _COMB_BACKEND
-    if name not in ("numpy", "pallas"):
-        raise ValueError(f"unknown combine backend: {name!r}")
-    prev, _COMB_BACKEND = _COMB_BACKEND, name
-    return prev
-
-
-def _pallas_sum_combine(msgs: Msgs) -> Msgs:
-    import jax.numpy as jnp
-
-    from repro.kernels.combine import segment_combine
-
-    uniq, inv = np.unique(msgs.keys, return_inverse=True)
-    out = segment_combine(jnp.asarray(inv, dtype=jnp.int32),
-                          jnp.asarray(msgs.vals, dtype=jnp.float32),
-                          num_segments=int(uniq.size))
-    return Msgs(uniq, np.asarray(out, dtype=np.float64))
-
-
-def combine_msgs(combiner: Combiner, msgs: Msgs) -> Msgs:
-    if _COMB_BACKEND == "pallas" and combiner.name == "sum" and msgs.n:
-        return _pallas_sum_combine(msgs)
-    return combiner(msgs)
-
 
 def vectorize_decline(cluster: LocalCluster, args: ShuffleArgs) -> str | None:
     """Why batched execution is invalid for this invocation, or ``None`` when
@@ -120,7 +82,7 @@ def _comb(args: ShuffleArgs, ledger, wid: int, batches) -> Msgs:
     if args.comb_fn is None:
         return batch
     ledger.charge_combine(wid, batch.nbytes, tenant=args.tenant)
-    return combine_msgs(args.comb_fn, batch)
+    return args.comb_fn(batch)
 
 
 def run_shuffle_vectorized(
@@ -404,7 +366,7 @@ def _fold_chunks(args: ShuffleArgs, ledger, wid: int, acc: Msgs | None,
     if args.comb_fn is None:
         return batch
     ledger.charge_combine(wid, piece.nbytes, chunk=chunk, tenant=args.tenant)
-    return combine_msgs(args.comb_fn, batch)
+    return args.comb_fn(batch)
 
 
 def _run_streamed_vectorized(

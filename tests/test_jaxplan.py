@@ -20,8 +20,6 @@ What the conformance matrix (test_conformance.py) does not already pin:
   longest segments differ a thousandfold), each member handed its slice;
 * the executor knob stack — per-call > per-tenant > cluster resolution;
 * plan-lifetime lowering reuse (``plancache.attach_lowering``);
-* the Pallas kernel plane (PART via ``partition_permute``, COMB via
-  ``segment_combine``) against the bit-exact default plane;
 * the COMB fold in rounds by rank against the row-serial ``lax.scan`` fold
   it replaced, byte for byte, alone and under ``vmap``, and the
   ``fold_rounds`` it reports;
@@ -41,9 +39,8 @@ from conformance import (ALL_TEMPLATES, assert_identical, conformance_case,
 from repro.core import (COMBINERS, SUM, Msgs, PartFn, TeShuCluster,
                         TeShuService, datacenter)
 from repro.core import jaxplan
-from repro.core.jaxplan import (_combine, kernel_global_stage, lower_plan,
-                                plan_decline, replay_cache_limit,
-                                replay_cache_size, set_kernel_plane,
+from repro.core.jaxplan import (_combine, lower_plan, plan_decline,
+                                replay_cache_limit, replay_cache_size,
                                 set_replay_cache_limit, trace_evictions,
                                 try_run_jax)
 from repro.core.plancache import get_lowering
@@ -262,49 +259,6 @@ def test_lower_plan_declines_unsupported_shapes():
     broken = dataclasses.replace(plan, dsts=tuple(WORKERS[:4]))
     assert plan_decline(broken) == "ring_mismatch"
     assert lower_plan(broken) is None
-
-
-# ---------------------------------------------------------------------------
-# the Pallas kernel plane
-# ---------------------------------------------------------------------------
-
-def test_kernel_plane_matches_exact_plane():
-    """With the kernel plane on, SUM replays route PART through
-    partition_permute and COMB through segment_combine: identical routing
-    (same keys per destination, same charges), float32-accumulated payloads."""
-    ref = conformance_case("vanilla_push", "uniform", "jax", comb_fn=SUM)[1]
-    prev = set_kernel_plane(True)
-    try:
-        hit = conformance_case("vanilla_push", "uniform", "jax",
-                               comb_fn=SUM)[1]
-    finally:
-        set_kernel_plane(prev)
-    assert hit.engine == "jax"
-    assert set(hit.bufs) == set(ref.bufs)
-    for d in ref.bufs:
-        np.testing.assert_array_equal(hit.bufs[d].keys, ref.bufs[d].keys)
-        np.testing.assert_allclose(hit.bufs[d].vals, ref.bufs[d].vals,
-                                   rtol=2e-5, atol=2e-5)
-    for k in ("total_bytes", "bytes_per_level", "recv_bytes_per_worker"):
-        assert hit.stats[k] == ref.stats[k]
-
-
-def test_kernel_global_stage_matches_numpy_fold():
-    """The fused kernel stage alone, against a plain numpy groupby oracle."""
-    rng = np.random.default_rng(11)
-    keys = rng.integers(0, 37, 500).astype(np.int64)
-    vals = rng.standard_normal((500, 3))
-    from repro.core import HASH_PART
-    per_dst = kernel_global_stage(HASH_PART, keys, vals, 4)
-    assert len(per_dst) == 4
-    slots = HASH_PART.assign(keys, 4)
-    for d, (kk, vv) in enumerate(per_dst):
-        mask = slots == d
-        expect = {k: vals[mask & (keys == k)].sum(axis=0)
-                  for k in np.unique(keys[mask])}
-        np.testing.assert_array_equal(kk, sorted(expect))
-        for i, k in enumerate(kk):
-            np.testing.assert_allclose(vv[i], expect[k], rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

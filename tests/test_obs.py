@@ -26,7 +26,8 @@ import os
 import numpy as np
 import pytest
 
-from conformance import copy_bufs, make_bufs, make_topology, service_for
+from conformance import (ALL_TEMPLATES, copy_bufs, make_bufs, make_topology,
+                         service_for, workers_for)
 from repro.core import (HASH_PART, SUM, Combiner, Msgs, ShuffleManager,
                         ShuffleRecord, TeShuCluster, TeShuService, datacenter)
 from repro.core.manager import JOURNAL_VERSION
@@ -459,19 +460,19 @@ def test_skewed_jax_exec_has_an_owner_merge_span():
     assert all(_within(s, exe) for s in stages)
 
 
-@pytest.mark.parametrize("case", ["vanilla_push", "network_aware", "skewed"])
+@pytest.mark.parametrize("case", ALL_TEMPLATES + ("skewed",))
 def test_jax_outputs_identical_with_tracing_on_and_off(case):
     if case == "skewed":
         topo, bufs, kw = _skewed_case()
-        template = "vanilla_push"
+        template, workers = "vanilla_push", WORKERS
     else:
-        topo, bufs, kw = None, make_bufs(WORKERS, "uniform", n=337), {
+        template, workers = case, workers_for(case)
+        topo, bufs, kw = None, make_bufs(workers, "uniform", n=337), {
             "comb_fn": SUM}
-        template = case
     out = {}
     for tracing in (False, True):
         sv = service_for("jax", topo, tracing=tracing)
-        res = _run_twice(sv, template, bufs, WORKERS, **kw)
+        res = _run_twice(sv, template, bufs, workers, **kw)
         assert res.engine == "jax"
         assert bool(sv.spans()) == tracing
         out[tracing] = res
@@ -512,18 +513,39 @@ def test_tracing_off_builds_no_annotation_and_adds_no_transfer(monkeypatch):
     assert sv.spans() == []
     assert counts == {"annotation": 0, "device_put": 0,
                       "block_until_ready": 0}
-    # the same replay traced: each counter sees its calls
+    # the same replay traced: it puts nothing on the device itself, and
+    # waits once a call, for the program's outputs
     sv.enable_tracing()
     for _ in range(2):
         sv.shuffle("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
                    comb_fn=SUM)
     assert counts["annotation"] == len(sv.spans())
-    assert counts["device_put"] == 2
-    assert counts["block_until_ready"] == 4
-    # operands on the device key one more entry of the program's cache than
-    # host arrays did (a retrace, not a compile); after that it is a hit
+    assert counts["device_put"] == 0
+    assert counts["block_until_ready"] == 2
+    # the traced calls take the untraced calls' operands, so they hit the
+    # program those compiled: no retrace
     jit = [s for s in sv.spans() if s["name"] == "jit_replay"]
-    assert jit[-1]["attrs"]["compiled"] is False
+    assert [s["attrs"]["compiled"] for s in jit] == [False, False]
+
+
+def test_traced_call_reuses_the_untraced_program():
+    """After untraced warm-up, a traced call of the same shape runs the
+    program the untraced calls compiled: the replay cache does not grow
+    and ``jit_replay`` says nothing compiled."""
+    from repro.core import replay_cache_size
+
+    sv = service_for("jax")
+    bufs = make_bufs(WORKERS, "uniform", n=367)     # a shape of its own
+    assert _run_twice(sv, "vanilla_push", bufs, WORKERS,
+                      comb_fn=SUM).engine == "jax"
+    before = replay_cache_size()
+    sv.enable_tracing()
+    res = sv.shuffle("vanilla_push", copy_bufs(bufs), WORKERS, WORKERS,
+                     comb_fn=SUM, shuffle_id=918)
+    assert res.engine == "jax"
+    assert replay_cache_size() == before
+    (jit,) = [s for s in sv.spans(918) if s["name"] == "jit_replay"]
+    assert jit["attrs"]["compiled"] is False
 
 
 def test_batched_dispatch_records_its_device_stages():
@@ -569,14 +591,29 @@ def _four_stream_pass(tracing):
                         comb_fn=SUM) for t in tenants]
     results = cl.run_pending()
     assert all(results[tk].batched for tk in tickets)
-    return cl
+    return cl, [results[tk] for tk in tickets]
+
+
+def test_batched_pass_identical_with_tracing_on_and_off():
+    """A batched ``run_pending`` pass gives every member the same outputs,
+    bit for bit, and the same ledger lanes whether it is traced or not."""
+    (_, off), (_, on) = _four_stream_pass(False), _four_stream_pass(True)
+    for a, b in zip(off, on):
+        assert a.engine == b.engine == "jax"
+        assert a.bufs.keys() == b.bufs.keys()
+        for d in a.bufs:
+            assert np.array_equal(a.bufs[d].keys, b.bufs[d].keys)
+            assert np.array_equal(a.bufs[d].vals, b.bufs[d].vals)
+        for lane in ("total_bytes", "bytes_per_level",
+                     "recv_bytes_per_worker", "bytes_per_tenant"):
+            assert a.stats[lane] == b.stats[lane]
 
 
 def test_run_pending_pass_is_a_span_tree():
     """A pass is rooted at ``run_pending``: the batch probe, the one batched
     dispatch (its host staging and device stages) and the members' shuffles
     nest under it, and each member's ``exec`` says it replayed a slice."""
-    cl = _four_stream_pass(tracing=True)
+    cl, _ = _four_stream_pass(tracing=True)
     spans = cl.spans()
     (root,) = [s for s in spans if s["name"] == "run_pending"]
     assert root["parent_id"] is None
@@ -634,7 +671,7 @@ def test_tracing_off_pass_builds_no_annotation_and_adds_no_transfer(
                                                    jax.device_put))
     monkeypatch.setattr(jax, "block_until_ready",
                         counted("block_until_ready", jax.block_until_ready))
-    cl = _four_stream_pass(tracing=False)
+    cl, _ = _four_stream_pass(tracing=False)
     assert cl.spans() == []
     assert counts == {"annotation": 0, "device_put": 0,
                       "block_until_ready": 0}

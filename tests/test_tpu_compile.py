@@ -20,8 +20,8 @@ from repro.core import jaxplan
 from repro.kernels.combine import _segment_combine
 from repro.kernels.partition import _partition_permute
 
-# The conformance fabric's kernel-plane shapes: 8 workers x 300 rows of
-# width 2, 64 distinct keys over 8 destinations.
+# The Pallas PART/COMB kernels at the conformance fabric's shapes: 8 workers
+# x 300 rows of width 2, 64 distinct keys over 8 destinations.
 PLANE_ROWS, PLANE_WIDTH, PLANE_SEGMENTS = 2400, 2, 8 * 64
 
 
@@ -51,9 +51,10 @@ def test_segment_combine_compiles_at_plane_shape(one_chip):
 
 
 def test_segment_combine_refuses_real_key_cardinality(one_chip):
-    """Why the kernel plane is off by default: its VMEM accumulator holds
-    one row per (destination, key) segment, so 16 destinations x 2,048 keys
-    — far fewer than a TPC-H group-by has — already do not fit."""
+    """Why the Pallas COMB cannot serve a real aggregation: its VMEM
+    accumulator holds one row per (destination, key) segment, so 16
+    destinations x 2,048 keys — far fewer than a TPC-H group-by has —
+    already do not fit."""
     with pytest.raises(Exception, match="(?i)vmem"):
         _segment_combine.lower(
             _sds((2048,), jnp.int32, one_chip),
